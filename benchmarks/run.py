@@ -2,7 +2,7 @@
 
 The old per-benchmark subprocess loop is gone — one process runs every
 workload through the WorkloadSpec registry, and multi-device workloads
-are satisfied by a single XLA_FLAGS host-platform re-exec when needed.
+get their CPU devices configured in-process.
 
   PYTHONPATH=src python -m repro.bench run              # everything
   PYTHONPATH=src python -m repro.bench run --tags smoke # CI smoke set

@@ -26,7 +26,7 @@ from repro.data.synthetic import synthetic_oscar_text
 from repro.data.tokenizer import ByteFallbackTokenizer
 from repro.models import lm
 from repro.power.ctxmgr import get_power
-from repro.power.methods import RaplPower, TPUModelPower
+from repro.power.methods import select_power_methods
 from repro.train.loop import LoopConfig, train_loop
 from repro.train.optimizer import OptConfig, opt_init
 from repro.train.step import StepConfig, make_train_step
@@ -74,9 +74,7 @@ def main():
 
     print(f"== 3. train {steps} steps (batch {gb} x seq {seq}) with "
           f"energy measurement")
-    rapl = RaplPower()
-    methods = [rapl] if rapl.available() else [
-        TPUModelPower(1, lambda: 1.0)]
+    methods, source = select_power_methods("auto")
     cfg = LoopConfig(total_steps=steps, ckpt_every=max(steps // 2, 10),
                      ckpt_dir=str(pathlib.Path(tmp) / "ckpt"),
                      log_every=max(steps // 6, 5), seq_len=seq,
@@ -90,7 +88,7 @@ def main():
     print(f"loss: {res.losses[0]:.3f} -> {res.losses[-1]:.3f} "
           f"(decreased: {res.losses[-1] < res.losses[0]})")
     print(f"throughput: {res.tokens_per_s:,.0f} tokens/s")
-    print(f"energy: {wh:.4f} Wh ({methods[0].name}) -> "
+    print(f"energy: {wh:.4f} Wh ({source}) -> "
           f"{n_tok / wh if wh else 0:,.0f} tokens/Wh")
     assert res.losses[-1] < res.losses[0], "training must reduce loss"
 

@@ -18,8 +18,8 @@ python -m pytest -x -q
 
 # 3. Every smoke-tagged workload end-to-end through the unified CLI on
 #    the deterministic synthetic power backend (multi-device workloads
-#    get their forced host platform via the CLI's XLA_FLAGS re-exec,
-#    sized to the largest placement in the selected sweeps).
+#    get CPU devices configured in-process by the CLI, sized to the
+#    largest placement in the selected sweeps).
 #    The serve workload's smoke points cover BOTH KV layouts
 #    (cache=slotted and cache=paged) on the XLA paged path; the
 #    llm_train/resnet50/heatmap smoke spaces each include a dp-scaling
@@ -97,22 +97,6 @@ print(f"prefix-cache gate: hits={pref['prefix_hit_requests']} "
       f"ttft_p99 ratio={ttft_ratio:.3f} wh/slo ratio={wh_ratio:.3f}")
 EOF
 
-# 3c. Paged decode-attention kernel drill: one serve cell with every
-#     decode step routed through the Pallas kernel in interpret mode on
-#     CPU (REPRO_PAGED_IMPL=pallas-interpret). This is a correctness
-#     gate only — interpret-mode timings are meaningless, so the run
-#     lands in a scratch dir and is never compared or promoted.
-#     Pinned to sched=phased: the sched axis would double the (slow)
-#     interpret cell count, and chunked decode runs the exact same
-#     paged-attention program (tests/test_chunked_serve.py covers the
-#     chunked paths at full fidelity). Pinned to kv_dtype=fp32 for the
-#     same reason — the int8 interpret coverage lives in the kernels
-#     smoke cases (paged_prefill_int8) and tests/test_int8_kv.py.
-rm -rf artifacts/ci-paged-kernel
-REPRO_PAGED_IMPL=pallas-interpret python -m repro.bench run --suite serve \
-    --points cache=paged,policy=continuous,sched=phased,kv_dtype=fp32 \
-    --tags smoke --power synthetic --out artifacts/ci-paged-kernel
-
 # 3d. TTFT-cliff gate (ISSUE 8 acceptance): on the tight-pool
 #     long_prefill trace, the chunked scheduler must hold its median
 #     ttft_p99 at <= 0.7x phased with goodput no worse, token streams
@@ -163,34 +147,6 @@ print(f"resilience gate: restarts={m['restarts']} "
       f"wasted_tokens={m['wasted_tokens']}<={bound} "
       f"recovery_s={m['recovery_s']:.3f} loss_bitmatch=1 "
       f"wh_overhead={m['wh_overhead_resilience']:.4f}")
-EOF
-
-# 3f. Paged prefill-attention kernel drill (ISSUE 10): one serve_slo
-#     cell whose shared-prefix hits route every suffix prefill through
-#     the Pallas paged-prefill kernel in interpret mode
-#     (engine._prefix_prefill_fn -> lm.prefill(paged_prefix=...) ->
-#     kernels.prefill_attention). Correctness-drill only, like 3c: the
-#     run proves the scalar-prefetch block-table walk executes end to
-#     end on this host; oracle bit-exactness is pytest's job
-#     (tests/test_prefill_kernel.py, tests/test_prefix_cache.py).
-rm -rf artifacts/ci-prefill-kernel
-REPRO_PAGED_IMPL=pallas-interpret python -m repro.bench run \
-    --suite serve_slo \
-    --points trace=shared_prefix,cache=paged+prefix,sched=phased,kv_dtype=fp32 \
-    --tags smoke --power synthetic --out artifacts/ci-prefill-kernel
-python - <<'EOF'
-import json, sys
-recs = json.load(
-    open("artifacts/ci-prefill-kernel/serve_slo/results.json"))["records"]
-ok = [r for r in recs if r["status"] == "ok"]
-if not ok:
-    sys.exit("paged-prefill kernel drill produced no ok cell")
-hits = ok[0]["metrics"].get("prefix_hit_requests", 0)
-if hits <= 0:
-    sys.exit("paged-prefill kernel drill never hit the prefix index — "
-             "the Pallas prefill path was not exercised")
-print(f"paged-prefill kernel drill: {hits} prefix-hit requests through "
-      f"the interpret-mode kernel")
 EOF
 
 # 3g. int8 KV-block gate (ISSUE 10 acceptance): every paged continuous

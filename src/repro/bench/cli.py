@@ -10,16 +10,15 @@
                             [--rel-tol m=0.1,default=0.3] [--report md|csv]
                             [--report-out FILE] [--suite a,b]
 
-Replaces the old per-benchmark subprocess driver: one process runs every
-selected workload, sharing the jax runtime. Multi-device workloads are
-satisfied by configuring the host platform device count up front —
+One process runs every selected workload, sharing the jax runtime, and
+never starts another: a process that has touched JAX holds the chip.
+On a CPU host, multi-device workloads get their devices in-process —
+the CPU device count is configured before the backend initializes,
 sized to the largest mesh any selected point's ``placement`` needs
-(capped at ``REPRO_MAX_LOCAL_DEVICES``, default 8) — in-process where
-the jax version supports it, otherwise by re-exec'ing once with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before the
-backend initializes. A placement beyond the cap is not an error: the
-runner records those points as ``deferred`` with a rendered
-``launch.slurm`` job script sized to the mesh.
+(capped at ``REPRO_MAX_LOCAL_DEVICES``, default 8); a placement beyond
+the cap is recorded as ``deferred`` with a rendered ``launch.slurm``
+job script. On an accelerator a point wider than the devices present
+is an error, and ``run`` exits non-zero.
 
 Each record also prints the classic ``name,us_per_call,derived`` CSV
 line, so existing log scrapers keep working.
@@ -29,11 +28,9 @@ from __future__ import annotations
 import argparse
 import os
 import pathlib
-import subprocess
 import sys
 from typing import Optional, Sequence
 
-from repro.bench import envtune
 from repro.bench import workloads  # noqa: F401 - populates the registry
 from repro.bench.compare import (
     MISSING, NOISE_K, POWER_MISMATCH, compare_sets, load_result_set,
@@ -44,10 +41,9 @@ from repro.bench.runner import WorkloadRunner
 from repro.bench.spec import (
     UnknownWorkloadError, get_workload, iter_workloads,
 )
+from repro.core.device import enable_compile_cache
 from repro.core.results import heatmap, table
 
-_REEXEC_MARKER = "REPRO_BENCH_REEXEC"
-_FORCE_FLAG = "--xla_force_host_platform_device_count"
 #: ceiling on forced host-platform devices — a dp64 placement point must
 #: defer to a rendered Slurm job, not fork 64 CPU "devices"
 _LOCAL_DEVICE_CAP_ENV = "REPRO_MAX_LOCAL_DEVICES"
@@ -93,49 +89,18 @@ def _select(args) -> list:
         raise SystemExit(f"error: {e}")
 
 
-def ensure_devices(needed: int, argv: Sequence[str]) -> Optional[int]:
-    """Make >= ``needed`` jax devices available to this run, with any
-    opt-in environment tuning (``envtune``: tcmalloc preload, XLA step
-    marker) applied.
-
-    Returns None when the current process can proceed; otherwise re-execs
-    the CLI once with the host platform device count forced via XLA_FLAGS
-    and/or the tuned environment prepared (both must land before the
-    dynamic loader / jax backend init in the child) and returns its exit
-    code.
-    """
-    tuning = envtune.pending()
-    if needed <= 1 and not tuning:
-        return None
+def ensure_cpu_devices(needed: int) -> None:
+    """Give the CPU backend ``needed`` devices, configured in-process
+    before the backend initializes. It touches no accelerator; where a
+    backend is already up (an in-process caller), the device count
+    stands and points wider than it are handled by the runner."""
+    if needed <= 1:
+        return
     import jax
-    if needed > 1:
-        try:
-            # newer jax: in-process host-platform config (pre-backend-init)
-            jax.config.update("jax_num_cpu_devices", needed)
-        except Exception:  # noqa: BLE001 - option missing or backend is up
-            pass
-    if jax.device_count() >= needed and not tuning:
-        return None
-    if os.environ.get(_REEXEC_MARKER):
-        if jax.device_count() >= needed:
-            return None   # tuning was applied by the exec that got us here
-        raise SystemExit(
-            f"error: {needed} devices required but only "
-            f"{jax.device_count()} available even after forcing "
-            f"the host platform device count")
-    env = dict(os.environ)
-    if jax.device_count() < needed:
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" {_FORCE_FLAG}={needed}").strip()
-    env = envtune.apply(env) if tuning else env
-    env[_REEXEC_MARKER] = "1"
-    # the child must find repro even when the parent got it via sys.path
-    src_dir = str(pathlib.Path(__file__).resolve().parents[2])
-    env["PYTHONPATH"] = ":".join(
-        p for p in (src_dir, env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.bench", *argv], env=env)
-    return proc.returncode
+    try:
+        jax.config.update("jax_num_cpu_devices", needed)
+    except RuntimeError:   # backends already initialized in this process
+        pass
 
 
 def _emit_lines(spec, records) -> None:
@@ -173,7 +138,7 @@ def cmd_list(args) -> int:
     return 0
 
 
-def cmd_run(args, argv: Sequence[str]) -> int:
+def cmd_run(args) -> int:
     specs = _select(args)
     if not specs:
         print("no workloads selected")
@@ -190,9 +155,8 @@ def cmd_run(args, argv: Sequence[str]) -> int:
             return s.max_devices(smoke)
 
     needed = max(devices_for(s) for s in specs)
-    rc = ensure_devices(min(needed, local_device_cap()), argv)
-    if rc is not None:
-        return rc
+    ensure_cpu_devices(min(needed, local_device_cap()))
+    enable_compile_cache()
     failures = []
     for spec in specs:
         print(f"\n###### {spec.name} — {spec.analog} ######", flush=True)
@@ -417,7 +381,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.cmd == "list":
         return cmd_list(args)
     if args.cmd == "run":
-        return cmd_run(args, argv)
+        return cmd_run(args)
     if args.cmd == "compare":
         return cmd_compare(args)
     return cmd_report(args)

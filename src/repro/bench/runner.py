@@ -14,10 +14,12 @@ to the build through ``ctx.placement``/``ctx.mesh()``, sizes the power
 backend to the point's mesh (per-device attribution: a dp4 cell is
 billed four devices' watts, not one), and stamps the cross-placement
 scaling metrics (``records.stamp_scaling_metrics``) into every sweep.
-A point whose mesh exceeds the local device count is not an error: the
-runner renders a ``launch.slurm`` job script sized to the mesh and
-records the point as ``deferred`` — the sweep's local cells still
-measure, and the script carries the oversized cell to the cluster.
+On a CPU host a point whose mesh exceeds the local device count is not
+an error: the runner renders a ``launch.slurm`` job script sized to the
+mesh and records the point as ``deferred`` — the sweep's local cells
+still measure, and the script carries the oversized cell to the
+cluster. On an accelerator such a point is an error: the chips present
+are the host under test.
 """
 from __future__ import annotations
 
@@ -28,7 +30,6 @@ import re
 import time
 from typing import Optional, Sequence
 
-from repro.bench import envtune
 from repro.bench.context import RunContext
 from repro.bench.records import (
     ResultRecord, save_records, stamp_scaling_metrics,
@@ -177,7 +178,13 @@ class WorkloadRunner:
         import jax
         have = jax.device_count()
         if placement.n_devices > have:
-            return self._defer_point(pt, placement, rec, have)
+            if jax.default_backend() == "cpu":
+                return self._defer_point(pt, placement, rec, have)
+            rec.status = "error"
+            rec.error = (f"mesh {placement.label} needs "
+                         f"{placement.n_devices} devices, this "
+                         f"{jax.default_backend()} host has {have}")
+            return rec
         ctx.placement = placement
         ctx.power_methods = self._power_for(placement.n_devices)
         t0 = time.perf_counter()
@@ -202,12 +209,6 @@ class WorkloadRunner:
             rec.metrics.update(metrics or {})
         if backoff_total > 0.0 or rec.attempts > 1:
             rec.metrics["retry_backoff_s"] = round(backoff_total, 6)
-        # environment-tuning provenance (tcmalloc preload / XLA step
-        # marker): a tuned run must never silently compare against an
-        # untuned baseline as if only the code changed
-        tuning = envtune.active()
-        if tuning:
-            rec.metrics["env_tuning"] = tuning
         dt = time.perf_counter() - t0
         if self.watchdog.observe(len(self.records), dt):
             rec.metrics["straggler"] = True

@@ -1,7 +1,8 @@
-"""Kernel microbenchmarks: Pallas (interpret) vs XLA reference.
+"""Kernel microbenchmarks: Pallas vs XLA reference.
 
-Interpret mode executes the kernel body in Python — the timing column is
-a correctness-scale signal only; the real figure of merit on TPU is the
+The Pallas kernels run compiled on a TPU and in interpret mode elsewhere.
+Interpret mode executes the kernel body in Python — there the timing
+column is a correctness-scale signal only; the real figure of merit on TPU is the
 roofline delta (flash attention removes the O(S*T) score traffic from
 the memory term). Power measurement is off: microsecond kernels are far
 below the power sampling interval.
@@ -85,7 +86,8 @@ def _paged_prefill_inputs(quantized: bool):
 def build(pt, ctx):
     """Pallas-vs-XLA kernel timing sweep."""
     case, impl = pt["case"], pt["impl"]
-    interpret = impl == "pallas"   # no compiled Pallas backend on CPU
+    # compiled on a TPU; off it, Pallas has only the interpreter
+    interpret = impl == "pallas" and jax.default_backend() != "tpu"
     if case.startswith("paged_prefill"):
         quantized = case.endswith("int8")
         (q, k_suf, v_suf, k_pool, v_pool, tables, k_sc, v_sc) = ctx.memo(

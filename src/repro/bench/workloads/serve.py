@@ -34,16 +34,12 @@ and ``wh_per_token_vs_fp_kv`` the perf/energy deltas, and
 ``kv_stream_prefix_agreement`` the token-stream quality vs the fp32
 twin's greedy streams (1.0 on the reduced config — quantization noise
 below the argmax margin). All cells share the
-batched-prefill + fused-decode serve loop. On CPU the paged
-cells run the XLA gather path of ``kernels.ops.paged_decode_attention``;
-set ``REPRO_PAGED_IMPL=pallas-interpret`` to push every decode step
-through the Pallas kernel in interpret mode instead (the CI correctness
-drill — orders of magnitude slower, never a timing baseline). Energy
-comes from the runner-selected power backend, labeled ``power_source``.
+batched-prefill + fused-decode serve loop. The paged cells run the
+Pallas paged-attention kernels on a TPU and their XLA reference
+elsewhere (``serve.engine.paged_kernel``). Energy comes from the
+runner-selected power backend, labeled ``power_source``.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -63,28 +59,14 @@ N_REQUESTS_SMOKE = 64   # enough that the drain tail amortizes away
 SEED = 0
 
 
-def _paged_impl() -> tuple[str, bool]:
-    """(paged_impl, interpret) from REPRO_PAGED_IMPL: "xla" (default
-    CPU measurement path), "pallas" (real TPU), "pallas-interpret"
-    (CI correctness drill on CPU)."""
-    mode = os.environ.get("REPRO_PAGED_IMPL", "xla")
-    if mode == "pallas-interpret":
-        return "pallas", True
-    if mode == "pallas":
-        return "pallas", False
-    return "xla", False
-
-
 def _engine(ctx, arch: str, n_slots: int, cache: str,
             kv_dtype: str = "fp32") -> ServeEngine:
     def make():
         c = get_config(arch).reduced()
         params = lm.init(jax.random.key(SEED), c)
-        impl, interpret = _paged_impl()
         engine = ServeEngine(c, params, n_slots=n_slots, max_len=MAX_LEN,
                              cache=cache, block_size=BLOCK_SIZE,
                              kv_dtype=kv_dtype,
-                             paged_impl=impl, paged_interpret=interpret,
                              power_methods=ctx.power_methods)
         # warmup: compile every serve program (prompt-bucket prefill,
         # insert, each paged gather bucket) outside any measured cell —
@@ -161,12 +143,6 @@ def build(pt, ctx):
     requests = poisson_requests(n, pt["rate_hz"], c.vocab,
                                 prompt_len=PROMPT_LEN, seed=SEED)
 
-    # interpret-mode kernel runs are the CI correctness drill: every
-    # number is discarded, so skip the noise repetition and the
-    # on-demand ratio baselines — one measured serve after warmup is
-    # the whole point (and interpret mode is far too slow to repeat)
-    drill = _paged_impl()[1]
-
     def run_cell():
         # two full repetitions of the cell: the second (steady-state) run
         # is reported, and the pair's throughput disagreement becomes the
@@ -175,19 +151,18 @@ def build(pt, ctx):
         # runner would fall back to the straggler watchdog's cross-point
         # spread, which mixes multi-second fixed cells with sub-second
         # continuous cells and saturates the compare-gate tolerance.
-        first = None if drill else engine.serve(
-            requests, policy=pt["policy"], sched=pt["sched"]).summary
+        first = engine.serve(requests, policy=pt["policy"],
+                             sched=pt["sched"]).summary
         out = engine.serve(requests, policy=pt["policy"],
                            sched=pt["sched"])
         s = out.summary
-        if first is not None:
-            pair = sorted((first.decode_tok_s, s.decode_tok_s))
-            spread = ((pair[1] - pair[0]) / ((pair[0] + pair[1]) / 2)
-                      if pair[1] > 0 else 0.0)
-            ctx.last_measurement = Measurement(
-                seconds=s.wall_s, energy_wh=s.attributed_wh,
-                power_source=ctx.power_source, iters=2, warmup=0,
-                rel_spread=spread)
+        pair = sorted((first.decode_tok_s, s.decode_tok_s))
+        spread = ((pair[1] - pair[0]) / ((pair[0] + pair[1]) / 2)
+                  if pair[1] > 0 else 0.0)
+        ctx.last_measurement = Measurement(
+            seconds=s.wall_s, energy_wh=s.attributed_wh,
+            power_source=ctx.power_source, iters=2, warmup=0,
+            rel_spread=spread)
         metrics = {
             "n_requests": s.n_requests,
             "n_tokens": s.n_tokens,
@@ -244,7 +219,7 @@ def build(pt, ctx):
             if ref is not None:
                 metrics["kv_stream_prefix_agreement"] = stream_agreement(
                     ref, my_streams)
-        if pt["policy"] == "continuous" and not drill:
+        if pt["policy"] == "continuous":
             fixed = cells[cell_key].get("fixed")
             if fixed is None:
                 baseline = engine.serve(requests, policy="fixed",

@@ -71,7 +71,7 @@ from repro.serve.engine import ServeEngine
 from repro.serve.slo import SLO, evaluate_slo
 from repro.serve.traffic import TRACE_NAMES, generate_trace, preset_trace
 
-from repro.bench.workloads.serve import _paged_impl, stream_agreement
+from repro.bench.workloads.serve import stream_agreement
 
 MAX_LEN = 96            # slot capacity (prompt + budget; see traffic presets)
 BLOCK_SIZE = 16         # paged KV block; shared_prefix pins 3 full blocks
@@ -113,13 +113,11 @@ def _engine(ctx, arch: str, cache: str,
     def make():
         c = get_config(arch).reduced()
         params = lm.init(jax.random.key(SEED), c)
-        impl, interpret = _paged_impl()
         engine = ServeEngine(c, params, n_slots=N_SLOTS, max_len=MAX_LEN,
                              cache="paged", block_size=BLOCK_SIZE,
                              n_blocks=n_blocks,
                              prefix_cache=cache == "paged+prefix",
                              kv_dtype=kv_dtype,
-                             paged_impl=impl, paged_interpret=interpret,
                              power_methods=ctx.power_methods)
         return c, engine
 
@@ -178,7 +176,6 @@ def build(pt, ctx):
     n = N_REQUESTS_SMOKE if ctx.smoke else N_REQUESTS
     cfg = preset_trace(pt["trace"], n_requests=n, vocab=c.vocab, seed=SEED)
     requests = generate_trace(cfg)
-    drill = _paged_impl()[1]
 
     # warm once per (engine, trace): compiles the trace's prefill
     # buckets and decode programs; repeat=2 lets a prefix engine
@@ -205,17 +202,16 @@ def build(pt, ctx):
             return engine.serve(requests, policy="continuous",
                                 sched=pt["sched"])
 
-        first = None if drill else one_run().summary
+        first = one_run().summary
         out = one_run()
         s = out.summary
-        if first is not None:
-            pair = sorted((first.decode_tok_s, s.decode_tok_s))
-            spread = ((pair[1] - pair[0]) / ((pair[0] + pair[1]) / 2)
-                      if pair[1] > 0 else 0.0)
-            ctx.last_measurement = Measurement(
-                seconds=s.wall_s, energy_wh=s.attributed_wh,
-                power_source=ctx.power_source, iters=2, warmup=0,
-                rel_spread=spread)
+        pair = sorted((first.decode_tok_s, s.decode_tok_s))
+        spread = ((pair[1] - pair[0]) / ((pair[0] + pair[1]) / 2)
+                  if pair[1] > 0 else 0.0)
+        ctx.last_measurement = Measurement(
+            seconds=s.wall_s, energy_wh=s.attributed_wh,
+            power_source=ctx.power_source, iters=2, warmup=0,
+            rel_spread=spread)
         report = evaluate_slo(out.results, SLO_BY_TENANT,
                               default=SLO_TIGHT)
         metrics = {

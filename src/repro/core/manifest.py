@@ -30,14 +30,15 @@ def git_sha() -> str | None:
 
 def build_manifest(extra: dict | None = None) -> dict:
     import jax
+
+    from repro.core import device
     m = {
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
         "git_sha": git_sha(),
         "python": sys.version.split()[0],
         "jax": jax.__version__,
-        "backend": jax.default_backend(),
-        "n_devices": jax.device_count(),
-        "platform": platform.platform(),
+        **device.current(),       # platform, device_kind, device_count
+        "host": platform.platform(),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "argv": sys.argv,
     }

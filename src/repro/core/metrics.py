@@ -18,8 +18,6 @@ import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
-from repro.roofline.analysis import PEAK_FLOPS_BF16
-
 J_PER_WH = 3600.0
 
 
@@ -53,7 +51,8 @@ def images_per_s(global_batch: int, iter_time_s: float) -> float:
 
 
 def mfu(model_flops_per_step: float, iter_time_s: float, n_chips: int,
-        peak: float = PEAK_FLOPS_BF16) -> float:
+        peak: float) -> float:
+    """``peak``: the chip's FLOP/s (``core.device.chip(kind)``)."""
     return model_flops_per_step / (max(iter_time_s, 1e-12) * n_chips * peak)
 
 

@@ -8,10 +8,14 @@ row, so attention work scales with the tokens a sequence actually owns
 instead of the padded slot capacity.
 
 TPU-native design (mirrors ``flash_attention.py``):
-  - grid ``(B, Kh, nb)``; the block dimension is innermost, which Pallas
+  - grid ``(B, nb)``; the block dimension is innermost, which Pallas
     TPU executes SEQUENTIALLY per core, so the online-softmax running
-    state (m, l, acc) lives in VMEM scratch and is carried across the
-    sequence's blocks;
+    state (m, l, acc) of every KV head lives in VMEM scratch and is
+    carried across the sequence's blocks;
+  - each grid step DMAs one whole pool block, all KV heads
+    ``(1, bs, Kh, Dh)``: its last two dims equal the pool's, which is
+    what Mosaic requires of a block whose head slice (1 row) is not a
+    multiple of 8. The kernel walks the heads in a static loop;
   - the block table and true lengths ride in as **scalar prefetch**
     arguments (``pltpu.PrefetchScalarGridSpec``): the k/v BlockSpec
     index map reads ``tables[b, j]`` to DMA the *physical* pool block —
@@ -25,9 +29,10 @@ TPU-native design (mirrors ``flash_attention.py``):
     positional (``kpos < length``), with the optional sliding window
     applied the same way as the slotted path;
   - int8 KV pools dequantize inside the load: per-block-per-head
-    symmetric scales ``(n_blocks, Kh)`` ride in as (1, 1) blocks
-    addressed by the same table lookup, and ``k * scale`` happens on the
-    VMEM tile — fp KV is never materialized anywhere.
+    symmetric scales ``(n_blocks, Kh)`` ride in through SMEM, one
+    block's ``(1, 1, Kh)`` row per grid step addressed by the same table
+    lookup, and ``k * scale`` happens on the VMEM tile — fp KV is never
+    materialized anywhere.
 
 Validated against ``kernels.ref.paged_decode_attention_ref`` in
 interpret mode (tests sweep block sizes, GQA groups, ragged lengths and
@@ -47,14 +52,14 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(tables_ref, lengths_ref, *refs, scale: float, bs: int,
-                   nb: int, window: Optional[int], quantized: bool):
+                   kh: int, nb: int, window: Optional[int], quantized: bool):
     if quantized:
         (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -69,32 +74,32 @@ def _decode_kernel(tables_ref, lengths_ref, *refs, scale: float, bs: int,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, Dh)
-        if quantized:
-            k = k * ksc_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < length
-        if window is not None:
-            mask = jnp.logical_and(mask, kpos > length - 1 - window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (bs, Dh)
-        if quantized:
-            v = v * vsc_ref[0, 0]
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-        m_scr[...] = m_new
+        for h in range(kh):       # static unroll over the block's KV heads
+            q = q_ref[0, h].astype(jnp.float32) * scale          # (G, Dh)
+            k = k_ref[0, :, h, :].astype(jnp.float32)            # (bs, Dh)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * ksc_ref[0, 0, h]
+                v = v * vsc_ref[0, 0, h]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
+            kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos < length
+            if window is not None:
+                mask = jnp.logical_and(mask, kpos > length - 1 - window)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[h]                                     # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+            acc_scr[h] = acc_scr[h] * alpha + pv
+            m_scr[h] = m_new
 
     @pl.when(j == nb - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, ...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -110,7 +115,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     f32 mark an int8 pool — blocks dequantize on their VMEM tile, fp KV
     is never materialized. Returns (B, H, Dh)."""
     b, h, dh = q.shape
-    bs, kh = k_pool.shape[1], k_pool.shape[2]
+    n_blocks, bs, kh = k_pool.shape[:3]
     assert h % kh == 0, (h, kh)
     assert (k_scale is None) == (v_scale is None)
     g = h // kh
@@ -118,38 +123,41 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     quantized = k_scale is not None
 
-    kernel = functools.partial(_decode_kernel, scale=scale, bs=bs, nb=nb,
-                               window=window, quantized=quantized)
+    kernel = functools.partial(_decode_kernel, scale=scale, bs=bs, kh=kh,
+                               nb=nb, window=window, quantized=quantized)
 
-    def kv_index(bi, khi, j, tables_ref, lengths_ref):
-        return (tables_ref[bi, j], 0, khi, 0)
+    def kv_index(bi, j, tables_ref, lengths_ref):
+        return (tables_ref[bi, j], 0, 0, 0)
 
-    def scale_index(bi, khi, j, tables_ref, lengths_ref):
-        return (tables_ref[bi, j], khi)
+    def scale_index(bi, j, tables_ref, lengths_ref):
+        return (tables_ref[bi, j], 0, 0)
+
+    def q_index(bi, j, tables_ref, lengths_ref):
+        return (bi, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, dh),
-                     lambda bi, khi, j, tr, lr: (bi, khi, 0, 0)),
-        pl.BlockSpec((1, bs, 1, dh), kv_index),
-        pl.BlockSpec((1, bs, 1, dh), kv_index),
+        pl.BlockSpec((1, kh, g, dh), q_index),
+        pl.BlockSpec((1, bs, kh, dh), kv_index),
+        pl.BlockSpec((1, bs, kh, dh), kv_index),
     ]
     operands = [q.reshape(b, kh, g, dh), k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), scale_index),
-                     pl.BlockSpec((1, 1), scale_index)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # (n_blocks, 1, Kh): the last two block dims then equal the
+        # array's, and SMEM holds one block's scales per grid step
+        in_specs += [pl.BlockSpec((1, 1, kh), scale_index,
+                                  memory_space=pltpu.SMEM)] * 2
+        operands += [s.astype(jnp.float32).reshape(n_blocks, 1, kh)
+                     for s in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, nb),
+        grid=(b, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, khi, j, tr, lr: (bi, khi, 0, 0)),
+        out_specs=pl.BlockSpec((1, kh, g, dh), q_index),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(

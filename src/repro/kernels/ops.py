@@ -1,8 +1,8 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-``impl="pallas"`` targets TPU (or interpret mode on CPU for validation);
-``impl="xla"`` routes to the pure-jnp reference path. The model code uses
-the XLA path for the CPU dry-run; real-TPU deployments flip the flag.
+``impl="pallas"`` targets TPU (interpret mode on CPU, in tests);
+``impl="xla"`` routes to the pure-jnp reference path. The serve engine
+picks between them from the platform (``serve.engine.paged_kernel``).
 
 Selection map (who runs what, where):
 
@@ -11,9 +11,8 @@ Selection map (who runs what, where):
   paged_decode_attention the serve decode hot path over a paged KV pool
                          (``serve.cache.PagedKVCache`` block tables).
                          ``impl="xla"`` gathers the table into a dense
-                         view (the CPU/dry-run path the benchmark
-                         measures); ``impl="pallas"`` walks the table
-                         with scalar-prefetch DMA — the TPU deployment
+                         view (the CPU path); ``impl="pallas"`` walks
+                         the table with scalar-prefetch DMA — the TPU
                          path, validated on CPU via ``interpret=True``.
   paged_prefill_attention the serve *prefill* hot path: a chunk of Q
                          positions vs [the slot's paged prefix blocks ++

@@ -11,10 +11,14 @@ pool.
 
 TPU-native design (the ``decode_attention.py`` block-table walk fused
 with the ``flash_attention.py`` online-softmax Q loop):
-  - grid ``(B, Kh, nQ, npre + nS)``; the KV dimension is innermost,
-    which Pallas TPU executes SEQUENTIALLY per core, so the
-    online-softmax running state (m, l, acc) lives in VMEM scratch and
+  - grid ``(B, nQ, npre + nS)``; the KV dimension is innermost, which
+    Pallas TPU executes SEQUENTIALLY per core, so the online-softmax
+    running state (m, l, acc) of every KV head lives in VMEM scratch and
     is carried across a query tile's prefix blocks and suffix tiles;
+  - KV blocks and suffix tiles carry all KV heads (``(1, bs, Kh, Dh)``,
+    ``(1, bk, Kh, Dh)``) so their last two dims equal the arrays' —
+    Mosaic's rule for blocks — and the kernel walks heads in a static
+    loop; query tiles are multiples of 8 rows (or the whole chunk);
   - the block table rides in as **scalar prefetch**
     (``pltpu.PrefetchScalarGridSpec``): for KV step ``j < npre`` the
     k/v BlockSpec index map reads ``tables[b, j]`` and DMAs the
@@ -31,9 +35,10 @@ with the ``flash_attention.py`` online-softmax Q loop):
     beyond the causal diagonal — and blocks/tiles outside the window —
     are skipped with ``pl.when`` (no MXU work);
   - int8 KV pools dequantize inside the load: per-block-per-head
-    symmetric scales ``(n_blocks, Kh)`` ride in as (1, 1) blocks
-    addressed by the same table lookup, and ``k * scale`` happens on
-    the VMEM tile — fp prefix KV is never materialized anywhere.
+    symmetric scales ``(n_blocks, Kh)`` ride in through SMEM, one
+    block's ``(1, 1, Kh)`` row per grid step addressed by the same table
+    lookup, and ``k * scale`` happens on the VMEM tile — fp prefix KV is
+    never materialized anywhere.
 
 Validated against ``kernels.ref.paged_prefill_attention_ref`` in
 interpret mode (tests sweep shapes, block sizes, GQA groups, prefix
@@ -53,7 +58,7 @@ NEG_INF = -1e30
 
 
 def _prefill_kernel(tables_ref, *refs, scale: float, bs: int, bq: int,
-                    bk: int, npre: int, n_kv: int, pos_offset: int,
+                    bk: int, kh: int, npre: int, n_kv: int, pos_offset: int,
                     window: Optional[int], quantized: bool):
     if quantized:
         (q_ref, kp_ref, vp_ref, ksc_ref, vsc_ref, ks_ref, vs_ref, o_ref,
@@ -61,8 +66,8 @@ def _prefill_kernel(tables_ref, *refs, scale: float, bs: int, bq: int,
     else:
         (q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
+    iq = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -73,10 +78,10 @@ def _prefill_kernel(tables_ref, *refs, scale: float, bs: int, bq: int,
     first_q = pos_offset + iq * bq
     last_q = first_q + bq - 1
 
-    def accum(k, v, kpos0):
-        """Online-softmax update with one KV tile (k/v: (tile, Dh) f32,
-        covering global positions [kpos0, kpos0 + tile))."""
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, bq, Dh)
+    def accum(h, k, v, kpos0):
+        """Online-softmax update of KV head ``h`` with one KV tile (k/v:
+        (tile, Dh) f32, covering global positions [kpos0, kpos0 + tile))."""
+        q = q_ref[0, h].astype(jnp.float32) * scale             # (G, bq, Dh)
         s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())))  # (G, bq, t)
         qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -84,14 +89,14 @@ def _prefill_kernel(tables_ref, *refs, scale: float, bs: int, bq: int,
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-        p = jnp.exp(s - m_new[..., None])
+        m_prev = m_scr[h]                                        # (G, bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2)
+        l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=2, keepdims=True)
         pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((), ())))
-        acc_scr[...] = acc_scr[...] * alpha[..., None] + pv
-        m_scr[...] = m_new
+        acc_scr[h] = acc_scr[h] * alpha + pv
+        m_scr[h] = m_new
 
     # prefix pool blocks: whole blocks of real tokens strictly below
     # pos_offset <= first_q, so causality never excludes them — only
@@ -102,12 +107,13 @@ def _prefill_kernel(tables_ref, *refs, scale: float, bs: int, bq: int,
 
     @pl.when(run_pre)
     def _pool_block():
-        k = kp_ref[0, :, 0].astype(jnp.float32)                # (bs, Dh)
-        v = vp_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ksc_ref[0, 0]
-            v = v * vsc_ref[0, 0]
-        accum(k, v, j * bs)
+        for h in range(kh):       # static unroll over the block's KV heads
+            k = kp_ref[0, :, h, :].astype(jnp.float32)           # (bs, Dh)
+            v = vp_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * ksc_ref[0, 0, h]
+                v = v * vsc_ref[0, 0, h]
+            accum(h, k, v, j * bs)
 
     # suffix tiles: global start pos_offset + (j - npre) * bk; tiles
     # past the causal diagonal of this q tile are skipped.
@@ -118,13 +124,24 @@ def _prefill_kernel(tables_ref, *refs, scale: float, bs: int, bq: int,
 
     @pl.when(run_suf)
     def _suffix_tile():
-        accum(ks_ref[0, :, 0].astype(jnp.float32),
-              vs_ref[0, :, 0].astype(jnp.float32), first_k)
+        for h in range(kh):
+            accum(h, ks_ref[0, :, h, :].astype(jnp.float32),
+                  vs_ref[0, :, h, :].astype(jnp.float32), first_k)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _tile(n: int, cap: int) -> int:
+    """Largest divisor of ``n`` that is at most ``cap`` and a multiple of
+    8 (Mosaic's sublane rule for the second-to-last block dim), else
+    ``n`` itself (a block equal to the whole dim is always legal)."""
+    for t in range(min(cap, n), 7, -1):
+        if n % t == 0 and t % 8 == 0:
+            return t
+    return n
 
 
 def paged_prefill_attention(q: jax.Array, k_suffix: jax.Array,
@@ -145,7 +162,7 @@ def paged_prefill_attention(q: jax.Array, k_suffix: jax.Array,
     whole blocks). Returns (B, Sq, H, Dh) in q.dtype.
     """
     b, sq, h, dh = q.shape
-    bs, kh = k_pool.shape[1], k_pool.shape[2]
+    n_blocks, bs, kh = k_pool.shape[:3]
     assert h % kh == 0, (h, kh)
     assert k_suffix.shape == (b, sq, kh, dh), (k_suffix.shape, (b, sq, kh, dh))
     assert (k_scale is None) == (v_scale is None)
@@ -154,64 +171,63 @@ def paged_prefill_attention(q: jax.Array, k_suffix: jax.Array,
     assert npre >= 1, "paged prefill needs >= 1 prefix block (cold " \
         "prefill with no prefix takes the dense path)"
     pos_offset = npre * bs
-    # tiles must divide Sq exactly; walk down from the requested size
-    # (engine buckets are block_size multiples, so this lands on a large
-    # divisor — e.g. Sq=144 with block_q=128 tiles at 72)
-    bq = min(block_q, sq)
-    while sq % bq:
-        bq -= 1
-    bk = min(block_k, sq)
-    while sq % bk:
-        bk -= 1
+    # tiles divide Sq exactly (engine buckets are block_size multiples,
+    # so this lands on a large divisor — e.g. Sq=144 with block_q=128
+    # tiles at 72)
+    bq = _tile(sq, block_q)
+    bk = _tile(sq, block_k)
     n_q, n_suf = sq // bq, sq // bk
     n_kv = npre + n_suf
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     quantized = k_scale is not None
 
     kernel = functools.partial(
-        _prefill_kernel, scale=scale, bs=bs, bq=bq, bk=bk, npre=npre,
+        _prefill_kernel, scale=scale, bs=bs, bq=bq, bk=bk, kh=kh, npre=npre,
         n_kv=n_kv, pos_offset=pos_offset, window=window, quantized=quantized)
 
-    def pool_index(bi, khi, iq, j, tables_ref):
+    def q_index(bi, iq, j, tables_ref):
+        return (bi, 0, 0, iq, 0)
+
+    def pool_index(bi, iq, j, tables_ref):
         # j >= npre clamps to the last prefix entry: a valid (never
         # computed-on) block, so the dead DMA cannot fault.
-        return (tables_ref[bi, jnp.minimum(j, npre - 1)], 0, khi, 0)
+        return (tables_ref[bi, jnp.minimum(j, npre - 1)], 0, 0, 0)
 
-    def scale_index(bi, khi, iq, j, tables_ref):
-        return (tables_ref[bi, jnp.minimum(j, npre - 1)], khi)
+    def scale_index(bi, iq, j, tables_ref):
+        return (tables_ref[bi, jnp.minimum(j, npre - 1)], 0, 0)
 
-    def suffix_index(bi, khi, iq, j, tables_ref):
-        return (bi, jnp.maximum(j - npre, 0), khi, 0)
+    def suffix_index(bi, iq, j, tables_ref):
+        return (bi, jnp.maximum(j - npre, 0), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, bq, dh),
-                     lambda bi, khi, iq, j, tr: (bi, khi, 0, iq, 0)),
-        pl.BlockSpec((1, bs, 1, dh), pool_index),
-        pl.BlockSpec((1, bs, 1, dh), pool_index),
+        pl.BlockSpec((1, kh, g, bq, dh), q_index),
+        pl.BlockSpec((1, bs, kh, dh), pool_index),
+        pl.BlockSpec((1, bs, kh, dh), pool_index),
     ]
     operands = [q.transpose(0, 2, 1, 3).reshape(b, kh, g, sq, dh),
                 k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), scale_index),
-                     pl.BlockSpec((1, 1), scale_index)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # (n_blocks, 1, Kh): the last two block dims then equal the
+        # array's, and SMEM holds one block's scales per grid step
+        in_specs += [pl.BlockSpec((1, 1, kh), scale_index,
+                                  memory_space=pltpu.SMEM)] * 2
+        operands += [s.astype(jnp.float32).reshape(n_blocks, 1, kh)
+                     for s in (k_scale, v_scale)]
     in_specs += [
-        pl.BlockSpec((1, bk, 1, dh), suffix_index),
-        pl.BlockSpec((1, bk, 1, dh), suffix_index),
+        pl.BlockSpec((1, bk, kh, dh), suffix_index),
+        pl.BlockSpec((1, bk, kh, dh), suffix_index),
     ]
     operands += [k_suffix, v_suffix]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kh, n_q, n_kv),
+        grid=(b, n_q, n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, bq, dh),
-                               lambda bi, khi, iq, j, tr: (bi, khi, 0, iq, 0)),
+        out_specs=pl.BlockSpec((1, kh, g, bq, dh), q_index),
         scratch_shapes=[
-            pltpu.VMEM((g, bq), jnp.float32),
-            pltpu.VMEM((g, bq), jnp.float32),
-            pltpu.VMEM((g, bq, dh), jnp.float32),
+            pltpu.VMEM((kh, g, bq, 1), jnp.float32),
+            pltpu.VMEM((kh, g, bq, 1), jnp.float32),
+            pltpu.VMEM((kh, g, bq, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -11,13 +11,8 @@ import jax
 
 
 def _mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # and on older jax every axis is implicitly auto — same semantics.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

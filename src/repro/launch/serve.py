@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.core.device import enable_compile_cache
 from repro.data.synthetic import synthetic_tokens
 from repro.models import lm
 from repro.power.methods import select_power_methods
@@ -98,6 +99,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.rate <= 0:
         ap.error("--rate must be > 0 (Poisson arrival rate in req/s)")
+    enable_compile_cache()
 
     c = get_config(args.arch)
     if args.preset == "tiny":

@@ -39,7 +39,6 @@ TEMPLATE = """#!/bin/bash
 # (CARAML Sec. V: over-tight masks starve NCCL/ICI helper threads)
 export SLURM_CPU_BIND=none
 {env_exports}
-export REPRO_TPU=1
 # multi-pod rendezvous: first host of the allocation coordinates
 export JAX_COORDINATOR_ADDRESS=$(scontrol show hostnames $SLURM_JOB_NODELIST | head -n1):8476
 export JAX_NUM_PROCESSES=$SLURM_NTASKS

@@ -3,25 +3,10 @@
   PYTHONPATH=src python -m repro.launch.train --arch gpt-117m --preset tiny \
       --steps 50 --ckpt-dir /tmp/ckpt
 
-On a real TPU cluster each process runs this under the Slurm scripts from
-repro.launch.slurm with jax.distributed auto-init; on this CPU container
-it runs reduced configs end-to-end (the quickstart/benchmark path).
-
-XLA flags: latency-hiding scheduler + async collectives are enabled for
-TPU so FSDP all-gathers and gradient reduce-scatters overlap with compute
-(no-ops on CPU).
+On a TPU host the same command runs the chosen config (``--preset
+full`` for published widths); on a CPU container it runs reduced
+configs end-to-end (the quickstart/benchmark path).
 """
-import os
-
-TPU_PERF_FLAGS = (
-    " --xla_tpu_enable_latency_hiding_scheduler=true"
-    " --xla_tpu_megacore_fusion_allow_ags=true"
-    " --xla_enable_async_collective_permute=true"
-    " --xla_tpu_enable_async_collective_fusion=true"
-)
-if os.environ.get("REPRO_TPU"):
-    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + TPU_PERF_FLAGS
-
 import argparse
 import time
 
@@ -32,6 +17,7 @@ import numpy as np
 from repro.bench.spec import Placement
 from repro.configs import SHAPES, get_config
 from repro.configs.base import ShapeConfig
+from repro.core.device import enable_compile_cache
 from repro.core.manifest import write_manifest
 from repro.data.loader import ShardedLoader, lm_sample_fn
 from repro.data.synthetic import synthetic_tokens
@@ -131,6 +117,7 @@ def main(argv=None):
                     help="device mesh, e.g. dp4 or dp2tp2 — the same "
                          "Placement spelling the bench sweeps use")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     c = get_config(args.arch)
     if args.preset == "tiny":

@@ -32,7 +32,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.parallel.compat import shard_map
 from repro.parallel.compress import compressed_psum
 from repro.parallel.sharding import Plan, dp_size
 from repro.train.optimizer import OptConfig, opt_update
@@ -221,7 +220,7 @@ def make_dp_train_step(c: ModelConfig, oc: OptConfig,
         new_err = jnp.stack(errs)[None] if sync.mode == "int8" else err
         return grads, new_err, loss, ce, aux
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=plan.mesh,
         in_specs=(P(), P(plan.dp), P(plan.dp)),
         out_specs=(P(), P(plan.dp), P(), P(), P()),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 
 Params = Any
 
@@ -95,9 +94,9 @@ def pipeline_forward(mesh: Mesh, stage_axis: str, layer_fn: Callable,
         return outs
 
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(pspec, P()), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(pspec, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_microbatches)
 
 

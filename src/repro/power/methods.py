@@ -6,17 +6,19 @@ GH200-sysfs, we provide:
 
   rapl       — Linux powercap sysfs (real counters where the host has them;
                the direct analog of jpwr's `gh` hwmon-sysfs method)
-  tpu_model  — analytic TPU v5e power model driven by a utilization source
-               (roofline occupancy of the compiled step); TPUs expose no
-               user-space power counter, see DESIGN.md par.2.1
+  tpu_model  — analytic TPU power model driven by a utilization source
+               (roofline occupancy of the compiled step), with the
+               envelope of the device kind from ``core.device.CHIPS``;
+               TPUs expose no user-space power counter
   synthetic  — deterministic waveform, for tests and CI
 """
 from __future__ import annotations
 
 import os
 import time
-from dataclasses import dataclass, field
 from typing import Callable, Optional
+
+from repro.core import device
 
 
 class PowerMethod:
@@ -106,30 +108,28 @@ class RaplPower(PowerMethod):
         return out
 
 
-# TPU v5e power envelope (per chip). Idle fraction per public v5e studies.
-TPU_V5E_TDP_W = 220.0
-TPU_V5E_IDLE_W = 60.0
-
-
 class TPUModelPower(PowerMethod):
     """Analytic TPU power: P = P_idle + (P_TDP - P_idle) * utilization.
 
     ``utilization_fn`` is supplied by the benchmark runner: typically the
     roofline occupancy of the running step (compute_term / step_time from
-    the dry-run artifact), or a live duty-cycle estimate.
+    the dry-run artifact), or a live duty-cycle estimate. ``kind`` is
+    the ``device_kind`` whose envelope (``core.device.chip``) applies;
+    an unknown kind raises KeyError.
     """
 
     name = "tpu_model"
 
     def __init__(self, n_devices: int = 1,
-                 utilization_fn: Optional[Callable[[], float]] = None,
-                 tdp_w: float = TPU_V5E_TDP_W, idle_w: float = TPU_V5E_IDLE_W):
+                 utilization_fn: Optional[Callable[[], float]] = None, *,
+                 kind: str):
+        spec = device.chip(kind)
         self.n = n_devices
         self.utilization_fn = utilization_fn or (lambda: 0.0)
-        self.tdp_w, self.idle_w = tdp_w, idle_w
+        self.tdp_w, self.idle_w = spec.tdp_w, spec.idle_w
 
     def devices(self):
-        return [f"tpu_v5e:{i}" for i in range(self.n)]
+        return [f"tpu:{i}" for i in range(self.n)]
 
     def read(self):
         u = min(max(float(self.utilization_fn()), 0.0), 1.0)
@@ -205,17 +205,20 @@ def get_method(name: str, **kw) -> PowerMethod:
 def select_power_methods(prefer: str = "auto", *, n_devices: int = 1,
                          utilization_fn: Optional[Callable[[], float]] = None,
                          ) -> tuple[list[PowerMethod], str]:
-    """Pick the measurement backend for this host: RAPL -> TPU-model ->
-    synthetic, returning ``(methods, source_label)``.
+    """Pick the measurement backend for this process, returning
+    ``(methods, source_label)``.
 
     The label is stamped into every result record as ``power_source`` so a
     reader can always tell measured counters from modeled or synthetic
     power. ``prefer`` forces a specific backend (or ``"none"`` to disable
-    measurement); ``"auto"`` walks the preference order:
+    measurement); ``"auto"`` decides from the platform JAX runs on
+    (``core.device.current``):
 
-      rapl       — real powercap counters, when the host exposes them
-      tpu_model  — analytic model, when running on an actual TPU backend
-                   (TPUs expose no user-space counter) or REPRO_TPU is set
+      tpu_model  — on a TPU: the analytic model of that device kind
+                   (TPUs expose no user-space counter; the host's RAPL
+                   counters would bill the host CPU as the run's energy)
+      rapl       — elsewhere, real powercap counters when the host
+                   exposes them
       synthetic  — deterministic waveform everywhere else (CPU CI hosts),
                    so energy columns stay populated but clearly labeled
     """
@@ -229,30 +232,23 @@ def select_power_methods(prefer: str = "auto", *, n_devices: int = 1,
         if prefer in ("synthetic", "tpu_model"):
             kw["n_devices"] = n_devices
         if prefer == "tpu_model":
-            kw["utilization_fn"] = utilization_fn or _roofline_utilization()
+            return [_tpu_model(n_devices, utilization_fn)], prefer
         return [METHODS[prefer](**kw)], prefer
+    if device.current()["platform"] == "tpu":
+        return [_tpu_model(n_devices, utilization_fn)], "tpu_model"
     rapl = RaplPower()
     if rapl.available():
         return [rapl], "rapl"
-    on_tpu = bool(os.environ.get("REPRO_TPU"))
-    if not on_tpu:
-        try:
-            import jax
-            on_tpu = jax.default_backend() == "tpu"
-        except Exception:  # noqa: BLE001 - no jax, no TPU
-            on_tpu = False
-    if on_tpu:
-        return [TPUModelPower(
-            n_devices=n_devices,
-            utilization_fn=utilization_fn or _roofline_utilization(),
-        )], "tpu_model"
     return [SyntheticPower(n_devices=n_devices)], "synthetic"
 
 
-def _roofline_utilization() -> Callable[[], float]:
-    """Default tpu_model utilization: roofline occupancy of the dry-run
-    artifacts, not the old constant 1.0 (which billed memory-bound steps
-    at full TDP). Falls back to 1.0 — with a logged warning — when no
-    roofline data exists."""
+def _tpu_model(n_devices: int,
+               utilization_fn: Optional[Callable[[], float]]) -> TPUModelPower:
+    """The analytic model of this process's device kind. Without an
+    explicit ``utilization_fn`` it bills the roofline occupancy of the
+    dry-run artifacts, and raises where there are none."""
     from repro.power.utilization import roofline_utilization_fn
-    return roofline_utilization_fn(default=1.0)
+    return TPUModelPower(
+        n_devices=n_devices,
+        utilization_fn=utilization_fn or roofline_utilization_fn(),
+        kind=device.current()["device_kind"])

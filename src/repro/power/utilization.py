@@ -3,19 +3,15 @@
 ``TPUModelPower`` converts a utilization fraction into watts
 (``P = idle + (TDP - idle) * u``); this module supplies that fraction
 from the roofline occupancy of the compiled steps (the dry-run
-artifacts under ``artifacts/dryrun/``) instead of the old constant 1.0
-— which billed every modeled run at full TDP regardless of occupancy
-and overreported energy for memory-/collective-bound cells.
+artifacts under ``artifacts/dryrun/``). Where none exist it raises
+rather than bill a constant.
 """
 from __future__ import annotations
 
 import json
-import logging
 import os
 import pathlib
 from typing import Callable, Optional
-
-log = logging.getLogger(__name__)
 
 
 def _dryrun_dir(override: Optional[str] = None) -> pathlib.Path:
@@ -47,23 +43,19 @@ def roofline_fractions(dryrun_dir=None) -> list[float]:
     return out
 
 
-def roofline_utilization_fn(dryrun_dir=None, default: float = 1.0,
-                            ) -> Callable[[], float]:
+def roofline_utilization_fn(dryrun_dir=None) -> Callable[[], float]:
     """A ``TPUModelPower.utilization_fn`` backed by roofline occupancy.
 
     Averages the ``roofline_fraction`` across the dry-run artifacts —
-    the occupancy of the compiled steps this host would run. Falls back
-    to ``default`` (with a logged warning) when no roofline data exists,
-    so modeled power stays populated on fresh checkouts.
+    the occupancy of the compiled steps this host would run. Raises
+    FileNotFoundError where no roofline data exists: billing a constant
+    instead would pass a guess off as the device's modeled energy.
     """
     fracs = roofline_fractions(dryrun_dir)
     if not fracs:
-        log.warning(
-            "tpu_model power: no roofline dry-run artifacts under %s; "
-            "utilization falls back to %.2f (full-TDP billing) — run "
-            "`python -m repro.launch.dryrun` to ground it in occupancy",
-            _dryrun_dir(dryrun_dir), default)
-        u = default
-    else:
-        u = sum(fracs) / len(fracs)
+        raise FileNotFoundError(
+            f"tpu_model power needs a utilization source: no roofline "
+            f"dry-run artifacts under {_dryrun_dir(dryrun_dir)} — run "
+            f"`python -m repro.launch.dryrun`, or measure with --power none")
+    u = sum(fracs) / len(fracs)
     return lambda: u

@@ -14,11 +14,10 @@ from dataclasses import asdict, dataclass
 from typing import Optional
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.core.device import chip
 
-# TPU v5e per-chip constants (given)
-PEAK_FLOPS_BF16 = 197e12         # FLOP/s
-HBM_BW = 819e9                   # B/s
-ICI_BW = 50e9                    # B/s per link (conservative: 1 link)
+#: the device kind the dry run models (its meshes stand for v5e pods)
+DRYRUN_KIND = "TPU v5 lite"
 
 
 @dataclass
@@ -55,9 +54,10 @@ def analyze(c: ModelConfig, shape: ShapeConfig, *, mesh_name: str,
             n_devices: int, flops_per_device: float,
             hbm_bytes_per_device: float,
             wire_bytes_per_device: float) -> Roofline:
-    compute_s = flops_per_device / PEAK_FLOPS_BF16
-    memory_s = hbm_bytes_per_device / HBM_BW
-    coll_s = wire_bytes_per_device / ICI_BW
+    spec = chip(DRYRUN_KIND)
+    compute_s = flops_per_device / spec.peak_bf16_flops
+    memory_s = hbm_bytes_per_device / spec.hbm_bw
+    coll_s = wire_bytes_per_device / spec.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     bottleneck = max(terms, key=terms.get)
     mf = model_flops(c, shape)

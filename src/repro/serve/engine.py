@@ -114,6 +114,14 @@ def make_decode_fn(c: ModelConfig, impl: str = "grouped"):
     return serve_step
 
 
+def paged_kernel() -> tuple[str, bool]:
+    """``(impl, interpret)`` of the paged attention ops, chosen from the
+    platform: the Pallas kernels on a TPU, the XLA reference
+    (``kernels.ref``) elsewhere. Interpret mode is for tests, which
+    steer this function themselves."""
+    return ("pallas" if jax.default_backend() == "tpu" else "xla"), False
+
+
 @dataclass
 class GenerationResult:
     tokens: Any
@@ -176,7 +184,6 @@ class ServeEngine:
                  kv_dtype: str = "fp32",
                  decode_window: int = 8,
                  sched: str = "phased", chunk_tokens: int = 32,
-                 paged_impl: str = "xla", paged_interpret: bool = False,
                  prefill_fn: Optional[Callable] = None,
                  decode_fn: Optional[Callable] = None,
                  clock: Callable[[], float] = time.monotonic,
@@ -208,7 +215,7 @@ class ServeEngine:
         self.preemptions = 0          # preempt events in the last serve()
         self.shed = 0                 # requests shed in the last serve()
         self.injected_faults = 0      # slot faults fired in the last serve()
-        self.paged_impl, self.paged_interpret = paged_impl, paged_interpret
+        self.paged_impl, self.paged_interpret = paged_kernel()
         self.impl_prefill = impl_prefill
         self.impl_decode, self.donate = impl_decode, donate
         self.clock = clock

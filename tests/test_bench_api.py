@@ -251,7 +251,7 @@ def test_cli_run_and_report_roofline(tmp_path, capsys):
 @pytest.mark.slow
 def test_cli_smoke_suite_end_to_end():
     """The CI gate: every smoke-tagged workload through one CLI call on
-    synthetic power (multi-device workloads via the XLA_FLAGS re-exec)."""
+    synthetic power (multi-device workloads on in-process CPU devices)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

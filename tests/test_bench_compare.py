@@ -628,25 +628,36 @@ def _run_auto(tmp_path, name):
 
 
 def test_power_fallback_chain_end_to_end(tmp_path, monkeypatch):
-    """RAPL unavailable -> TPU model -> synthetic, with the winning label
-    stamped into the records compare joins on."""
-    # stage 1: fake powercap sysfs present -> rapl wins
+    """TPU platform -> TPU model (even with RAPL present), else RAPL,
+    else synthetic, with the winning label stamped into the records
+    compare joins on. The platform is steered through
+    ``core.device.current``."""
+    from repro.core import device
     zone = tmp_path / "powercap" / "intel-rapl:0"
     zone.mkdir(parents=True)
     (zone / "energy_uj").write_text("123456\n")
     monkeypatch.setattr(RaplPower, "ROOT", str(tmp_path / "powercap"))
-    monkeypatch.setenv("REPRO_TPU", "1")           # rapl must still win
-    r1 = _run_auto(tmp_path / "o1", "toy_rapl")
-    assert r1.power_source == "rapl"
-    # stage 2: no RAPL, TPU flagged -> analytic model
-    monkeypatch.setattr(RaplPower, "ROOT", str(tmp_path / "empty"))
+    dry = tmp_path / "dryrun"
+    dry.mkdir()
+    (dry / "step.json").write_text(
+        json.dumps({"roofline": {"roofline_fraction": 0.5}}))
+    monkeypatch.setenv("REPRO_DRYRUN_DIR", str(dry))
+    cpu = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
+    tpu = {"platform": "tpu", "device_kind": "TPU v5 lite",
+           "device_count": 2}
+    # stage 1: a TPU platform -> analytic model, RAPL notwithstanding
+    monkeypatch.setattr(device, "current", lambda: tpu)
     methods, src = select_power_methods("auto", n_devices=2)
     assert src == "tpu_model" and len(methods[0].devices()) == 2
-    r2 = _run_auto(tmp_path / "o2", "toy_tpu")
-    assert r2.power_source == "tpu_model"
-    assert r2.metrics.get("tokens_per_s") == 1.0
+    r1 = _run_auto(tmp_path / "o1", "toy_tpu")
+    assert r1.power_source == "tpu_model"
+    assert r1.metrics.get("tokens_per_s") == 1.0
+    # stage 2: CPU platform with powercap sysfs present -> rapl
+    monkeypatch.setattr(device, "current", lambda: cpu)
+    r2 = _run_auto(tmp_path / "o2", "toy_rapl")
+    assert r2.power_source == "rapl"
     # stage 3: no RAPL, no TPU -> deterministic synthetic floor
-    monkeypatch.delenv("REPRO_TPU")
+    monkeypatch.setattr(RaplPower, "ROOT", str(tmp_path / "empty"))
     r3 = _run_auto(tmp_path / "o3", "toy_synth")
     assert r3.power_source == "synthetic"
     # the three labels never join silently: same point, disjoint keys

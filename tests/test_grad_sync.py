@@ -97,7 +97,6 @@ def test_bucketed_sync_matches_naive_psum_and_single_device(subproc):
     a shard_map step using naive per-leaf psum and (b) the plain
     single-logical-batch GSPMD step."""
     subproc(_DP_PRELUDE + """
-from repro.parallel.compat import shard_map
 from jax.sharding import PartitionSpec as P
 from functools import partial
 
@@ -117,8 +116,8 @@ def naive_local(params, batch):
     g = jax.tree.map(lambda x: (x / k).astype(jnp.float32), g)
     return g, jax.lax.pmean(l / k, axis)
 
-smap = shard_map(naive_local, mesh=mesh, in_specs=(P(), P(plan.dp)),
-                 out_specs=(P(), P()), check_vma=False)
+smap = jax.shard_map(naive_local, mesh=mesh, in_specs=(P(), P(plan.dp)),
+                     out_specs=(P(), P()), check_vma=False)
 
 def naive_step(p, o, batch):
     g, l = smap(p, batch)
@@ -212,7 +211,6 @@ def test_compressed_psum_error_feedback_converges(subproc):
 import jax, jax.numpy as jnp
 import numpy as np
 from functools import partial
-from repro.parallel.compat import shard_map
 from repro.parallel.compress import compressed_psum
 from jax.sharding import PartitionSpec as P
 from jax.sharding import Mesh
@@ -225,10 +223,10 @@ def one_round(x, err):
     out, new_err = compressed_psum(x, "data", err)
     return out, new_err
 
-smap = jax.jit(shard_map(one_round, mesh=mesh,
-                         in_specs=(P("data"), P("data")),
-                         out_specs=(P("data"), P("data")),
-                         check_vma=False))
+smap = jax.jit(jax.shard_map(one_round, mesh=mesh,
+                             in_specs=(P("data"), P("data")),
+                             out_specs=(P("data"), P("data")),
+                             check_vma=False))
 true_mean = jnp.mean(x, axis=0)
 err = jnp.zeros_like(x)
 acc = jnp.zeros_like(true_mean)

@@ -140,12 +140,11 @@ import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
-from repro.parallel.compat import shard_map
 from repro.parallel.compress import compressed_psum
 
 mesh = make_mesh((8,), ("data",))
 
-@partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
          out_specs=(P("data"), P("data")), check_vma=False)
 def sync(g, e):
     out, e2 = compressed_psum(g[0], "data", e[0])

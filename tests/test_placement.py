@@ -294,6 +294,21 @@ def test_oversized_mesh_defers_with_rendered_slurm_script(tmp_path):
     assert {r.status for r in loaded} == {"ok", "deferred"}
 
 
+def test_oversized_mesh_on_an_accelerator_is_an_error(tmp_path,
+                                                      monkeypatch):
+    """On a chip host the chips present are the host under test: a point
+    wider than them fails (and so fails ``run``), it is never deferred."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner = WorkloadRunner(_toy_sweep_spec(), out_dir=str(tmp_path),
+                            power="none")
+    by = {r.point["placement"]: r for r in runner.run(verbose=False)}
+    assert by["dp1"].ok
+    assert by["dp64"].status == "error"
+    assert "needs 64 devices" in by["dp64"].error
+    assert "slurm_script" not in by["dp64"].metrics
+
+
 def test_compare_treats_deferred_as_missing_not_regression(tmp_path):
     ok = ResultRecord(workload="w", point={"placement": "dp64"},
                       metrics={"tokens_per_s": 1.0}, placement={"dp": 64})

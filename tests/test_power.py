@@ -7,6 +7,7 @@ import time
 import pytest
 from _prop import given, settings, st
 
+from repro.core import device
 from repro.power.ctxmgr import MeasuredScope, expand_suffix, get_power
 from repro.power.frame import Frame
 from repro.power.methods import (
@@ -73,9 +74,13 @@ def test_background_thread_sampling():
     assert e > 0
 
 
+V5E = {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}
+
+
 def test_tpu_model_power_utilization():
     util = {"v": 0.0}
-    m = TPUModelPower(n_devices=4, utilization_fn=lambda: util["v"])
+    m = TPUModelPower(n_devices=4, utilization_fn=lambda: util["v"],
+                      kind="TPU v5 lite")
     assert all(abs(w - 60.0) < 1e-9 for w in m.read().values())
     util["v"] = 1.0
     assert all(abs(w - 220.0) < 1e-9 for w in m.read().values())
@@ -172,18 +177,18 @@ def test_roofline_utilization_averages_dryrun_fractions(tmp_path):
     assert fn() == pytest.approx((0.2 + 0.6 + 1.0) / 3)
 
 
-def test_roofline_utilization_falls_back_with_warning(tmp_path, caplog):
-    with caplog.at_level("WARNING", logger="repro.power.utilization"):
-        fn = roofline_utilization_fn(dryrun_dir=str(tmp_path / "missing"),
-                                     default=1.0)
-    assert fn() == 1.0
-    assert any("roofline" in r.message for r in caplog.records)
+def test_roofline_utilization_falls_back_with_warning(tmp_path):
+    """No roofline data: no fallback to a constant (which billed full
+    TDP on a TPU), an error that names where it looked."""
+    with pytest.raises(FileNotFoundError, match="missing"):
+        roofline_utilization_fn(dryrun_dir=str(tmp_path / "missing"))
 
 
 def test_tpu_model_selection_wires_roofline_occupancy(tmp_path,
                                                      monkeypatch):
     """select_power_methods(prefer='tpu_model') must bill at roofline
     occupancy, not constant TDP, when dry-run artifacts exist."""
+    monkeypatch.setattr(device, "current", lambda: V5E)
     monkeypatch.setenv("REPRO_DRYRUN_DIR", str(tmp_path))
     _dryrun_artifact(tmp_path / "step.json", 0.25)
     methods, label = select_power_methods("tpu_model", n_devices=2)
@@ -193,3 +198,33 @@ def test_tpu_model_selection_wires_roofline_occupancy(tmp_path,
     want_w = m.idle_w + (m.tdp_w - m.idle_w) * 0.25
     assert all(w == pytest.approx(want_w) for w in m.read().values())
     assert want_w < m.tdp_w                      # no longer full-TDP
+
+
+def test_tpu_platform_picks_tpu_model_even_with_rapl(tmp_path, monkeypatch):
+    """On a TPU the host's RAPL counters measure the host CPU, not the
+    chip: auto must pick the device-kind model, with that kind's
+    envelope, and never RAPL."""
+    monkeypatch.setattr(RaplPower, "ROOT", str(_fake_powercap(tmp_path,
+                                                              1_000_000)))
+    assert RaplPower().available()
+    monkeypatch.setattr(device, "current", lambda: V5E)
+    methods, label = select_power_methods(
+        "auto", n_devices=2, utilization_fn=lambda: 0.5)
+    assert label == "tpu_model"
+    (m,) = methods
+    spec = device.chip("TPU v5 lite")
+    assert (m.tdp_w, m.idle_w) == (spec.tdp_w, spec.idle_w)
+    assert len(m.devices()) == 2
+    # off the TPU the same host's RAPL wins again
+    monkeypatch.setattr(device, "current", lambda: {
+        "platform": "cpu", "device_kind": "cpu", "device_count": 1})
+    assert select_power_methods("auto")[1] == "rapl"
+
+
+def test_tpu_model_of_unknown_device_kind_is_an_error(monkeypatch):
+    with pytest.raises(KeyError, match="TPU v9"):
+        TPUModelPower(kind="TPU v9")
+    monkeypatch.setattr(device, "current", lambda: {
+        "platform": "tpu", "device_kind": "TPU v9", "device_count": 1})
+    with pytest.raises(KeyError, match="TPU v9"):
+        select_power_methods("auto", utilization_fn=lambda: 0.5)

@@ -207,3 +207,51 @@ def test_engine_prefill_paths_dispatch_paged_kernel(monkeypatch):
         == {r.rid: r.tokens for r in base_prefix.results}
     assert {r.rid: r.tokens for r in out2.results} \
         == {r.rid: r.tokens for r in base_chunk.results}
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_engine_serves_through_pallas_kernels_in_interpret_mode(
+        monkeypatch, kv_dtype):
+    """The TPU serve path on CPU: with the engine's kernel choice steered
+    to the Pallas kernels (interpret mode), chunked serving dispatches
+    both paged kernels and yields the same greedy streams as the XLA
+    reference path the engine picks on CPU."""
+    from repro.serve import engine as engine_mod
+
+    c = get_config("llama3.2-3b").reduced(dtype="float32",
+                                          param_dtype="float32")
+    params = lm.init(jax.random.key(0), c)
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=i, prompt=rng.integers(0, c.vocab, p, np.int32),
+                    max_new_tokens=6)
+            for i, p in enumerate([48, 64, 40])]
+
+    def serve():
+        eng = ServeEngine(c, params, n_slots=3, max_len=96, cache="paged",
+                          block_size=16, kv_dtype=kv_dtype)
+        return eng, eng.serve(list(reqs), policy="continuous",
+                              sched="chunked")
+
+    ref_eng, ref_out = serve()
+    assert ref_eng.paged_impl == "xla"       # what CPU picks by itself
+
+    calls = []
+
+    def spy(real):
+        def wrapped(*args, **kw):
+            calls.append((real.__name__, kw["impl"], kw["interpret"]))
+            return real(*args, **kw)
+        return wrapped
+
+    monkeypatch.setattr(engine_mod, "paged_kernel", lambda: ("pallas", True))
+    monkeypatch.setattr(ops, "paged_decode_attention",
+                        spy(ops.paged_decode_attention))
+    monkeypatch.setattr(ops, "paged_prefill_attention",
+                        spy(ops.paged_prefill_attention))
+    _eng, out = serve()
+    assert {name for name, impl, interp in calls
+            if impl == "pallas" and interp} == {
+        "paged_decode_attention", "paged_prefill_attention"}
+    assert all(len(r.tokens) == 6 for r in out.results)
+    assert {r.rid: r.tokens for r in out.results} \
+        == {r.rid: r.tokens for r in ref_out.results}
