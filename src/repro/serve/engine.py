@@ -67,6 +67,20 @@ over it — yielding Wh/token and Wh/request per served request (the
 MLPerf-Power figure of merit). Fused windows credit each active rid once
 per micro-step, keeping the attribution exact.
 
+Spans and counters: each pass of the serve loop runs inside
+``jax.profiler.TraceAnnotation("serve.iter")`` with the children
+``serve.admit`` (shedding, refill, reservation, chunk start),
+``serve.prefill`` (one prefill program call: host arrays, dispatch,
+insert), ``serve.prefill.fetch``, ``serve.decode`` (growth, block
+tables, dispatch of the window's steps), ``serve.decode.fetch`` (the
+drain), ``serve.emit`` (token bookkeeping) and ``serve.wait`` (the sleep
+until the next arrival); the first call of each program key runs inside
+``serve.first_call``, so a compile inside a traced window shows by
+name. A prefill or decode span's ``step`` kwarg is the index of its
+``StepRecord``, whose counters (``iter``, ``rows``, ``width``,
+``used``) the span carries too. Only ints go in kwargs, and no span adds
+a device sync: with the profiler off a span costs a context entry.
+
 ``serve_step`` (single-token decode against a full KV cache) is what the
 ``decode_*`` / ``long_*`` dry-run shapes lower. ``BatchedServer`` remains
 as the thin fixed-batch wrapper the examples/tests drive.
@@ -80,6 +94,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.metrics import (
@@ -213,6 +228,7 @@ class ServeEngine:
         self.sched = sched
         self.chunk_tokens = int(chunk_tokens)
         self.preemptions = 0          # preempt events in the last serve()
+        self._iter = 0                # the serve loop's iteration
         self.shed = 0                 # requests shed in the last serve()
         self.injected_faults = 0      # slot faults fired in the last serve()
         self.paged_impl, self.paged_interpret = paged_kernel()
@@ -254,6 +270,7 @@ class ServeEngine:
                 serve_step, donate_argnums=(2,) if donate else ())
             self._paged_steps: dict = {}
             self._prefix_prefills: dict = {}
+            self._cold_prefills: dict = {}
             self.prefix_stats: dict = self._blank_prefix_stats()
             self._paged: Optional[PagedKVCache] = None
             #: admission-control ledger: slot -> worst-case block demand
@@ -315,8 +332,33 @@ class ServeEngine:
                 return (jnp.argmax(logits[:, -1], -1).astype(jnp.int32),
                         caches)
 
-            fn = jax.jit(step, donate_argnums=(2,) if self.donate else ())
+            fn = self._first_call(
+                jax.jit(step, donate_argnums=(2,) if self.donate else ()),
+                nb=nb)
             self._paged_steps[nb] = fn
+        return fn
+
+    def _first_call(self, fn: Callable, **key) -> Callable:
+        """``fn`` whose first call, where it traces and compiles (or
+        loads from the compile cache), runs inside ``serve.first_call``
+        with the program's key as kwargs."""
+        called = False
+
+        def call(*args):
+            nonlocal called
+            if called:
+                return fn(*args)
+            called = True
+            with TraceAnnotation("serve.first_call", **key):
+                return fn(*args)
+        return call
+
+    def _cold_prefill_fn(self, bucket: int):
+        """The batched prefill program at one prompt bucket (no prefix)."""
+        fn = self._cold_prefills.get(bucket)
+        if fn is None:
+            fn = self._first_call(self._serve_prefill, bucket=bucket, npre=0)
+            self._cold_prefills[bucket] = fn
         return fn
 
     def _prefix_prefill_fn(self, bucket: int, npre: int):
@@ -345,7 +387,8 @@ class ServeEngine:
                 first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
                 return first, rows
 
-            fn = jax.jit(prefill_hit)
+            fn = self._first_call(jax.jit(prefill_hit), bucket=bucket,
+                                  npre=npre)
             self._prefix_prefills[key] = fn
         return fn
 
@@ -568,72 +611,83 @@ class ServeEngine:
         for (bucket, npre), entries in sorted(groups.items()):
             kp = self.n_slots       # fixed batch: admission never retraces
             pre_len = npre * self.block_size
-            t0 = self.clock()
-            self._sample_power(ts, ws)   # bracket the prefill window
-            tokens = np.zeros((kp, bucket), np.int32)
-            last = np.zeros((kp,), np.int32)
-            slot_ids = np.full((kp,), self.n_slots, np.int32)  # pad: dropped
-            # pad rows gather the trash block — harmless, never read back
-            pre_blocks = np.zeros((kp, npre), np.int32)
-            for i, (slot, pre) in enumerate(entries):
-                plen = slot.request.prompt_len
-                prompt = np.asarray(slot.request.prompt, np.int32)
-                tokens[i, :plen - pre_len] = prompt[pre_len:]
-                last[i] = plen - pre_len - 1
-                slot_ids[i] = slot.index
-                if npre:
-                    pre_blocks[i] = pre
-            if npre:
-                first, rows = self._prefix_prefill_fn(bucket, npre)(
-                    self.params, self.caches, jnp.asarray(tokens),
-                    jnp.asarray(last), jnp.asarray(pre_blocks))
-            else:
-                first, rows = self._serve_prefill(self.params,
-                                                  jnp.asarray(tokens),
-                                                  jnp.asarray(last))
-            if self.cache_kind == "paged":
-                nbk = -(-bucket // self.block_size)
-                blocks = np.full((kp, nbk), self._paged.n_blocks, np.int32)
+            used = sum(s.request.prompt_len for s, _pre in entries) \
+                - pre_len * len(entries)
+            with TraceAnnotation("serve.prefill", step=len(steps),
+                                 iter=self._iter, rows=kp, bucket=bucket,
+                                 npre=npre, used=used):
+                t0 = self.clock()
+                self._sample_power(ts, ws)   # bracket the prefill window
+                tokens = np.zeros((kp, bucket), np.int32)
+                last = np.zeros((kp,), np.int32)
+                # pad rows: dropped
+                slot_ids = np.full((kp,), self.n_slots, np.int32)
+                # pad rows gather the trash block — harmless, never read back
+                pre_blocks = np.zeros((kp, npre), np.int32)
                 for i, (slot, pre) in enumerate(entries):
                     plen = slot.request.prompt_len
+                    prompt = np.asarray(slot.request.prompt, np.int32)
+                    tokens[i, :plen - pre_len] = prompt[pre_len:]
+                    last[i] = plen - pre_len - 1
+                    slot_ids[i] = slot.index
                     if npre:
-                        self._paged.adopt(slot.index, pre)
-                    self._paged.ensure(slot.index, plen)
-                    own = self._paged.block_ids(slot.index, plen)[npre:]
-                    blocks[i, :len(own)] = own
-                self.caches = insert_paged_prefill(
-                    self.caches, rows, jnp.asarray(blocks),
-                    jnp.asarray(slot_ids), block_size=self.block_size)
-                if use_prefix:
-                    st = self.prefix_stats
-                    st["hit_requests" if npre else
-                       "miss_requests"] += len(entries)
-                    st["reused_blocks"] += npre * len(entries)
-                    for slot, _pre in entries:
-                        st["registered_blocks"] += self._paged.prefix_register(
-                            slot.index,
-                            [int(t) for t in slot.request.prompt])
-            else:
-                self.caches = insert_rows(self.caches, rows,
-                                          jnp.asarray(slot_ids))
-            first_np = np.asarray(first)      # single batched device fetch
+                        pre_blocks[i] = pre
+                if npre:
+                    first, rows = self._prefix_prefill_fn(bucket, npre)(
+                        self.params, self.caches, jnp.asarray(tokens),
+                        jnp.asarray(last), jnp.asarray(pre_blocks))
+                else:
+                    first, rows = self._cold_prefill_fn(bucket)(
+                        self.params, jnp.asarray(tokens), jnp.asarray(last))
+                if self.cache_kind == "paged":
+                    nbk = -(-bucket // self.block_size)
+                    blocks = np.full((kp, nbk), self._paged.n_blocks,
+                                     np.int32)
+                    for i, (slot, pre) in enumerate(entries):
+                        plen = slot.request.prompt_len
+                        if npre:
+                            self._paged.adopt(slot.index, pre)
+                        self._paged.ensure(slot.index, plen)
+                        own = self._paged.block_ids(slot.index, plen)[npre:]
+                        blocks[i, :len(own)] = own
+                    self.caches = insert_paged_prefill(
+                        self.caches, rows, jnp.asarray(blocks),
+                        jnp.asarray(slot_ids), block_size=self.block_size)
+                    if use_prefix:
+                        st = self.prefix_stats
+                        st["hit_requests" if npre else
+                           "miss_requests"] += len(entries)
+                        st["reused_blocks"] += npre * len(entries)
+                        for slot, _pre in entries:
+                            st["registered_blocks"] += \
+                                self._paged.prefix_register(
+                                    slot.index,
+                                    [int(t) for t in slot.request.prompt])
+                else:
+                    self.caches = insert_rows(self.caches, rows,
+                                              jnp.asarray(slot_ids))
+            with TraceAnnotation("serve.prefill.fetch"):
+                first_np = np.asarray(first)  # single batched device fetch
             t1 = self.clock()
             self._sample_power(ts, ws)
             rids = tuple(s.request.rid for s, _pre in entries)
             slots = [s for s, _pre in entries]
-            steps.append(StepRecord("prefill", t0, t1, rids, len(rids)))
-            for i, slot in enumerate(slots):
-                res = results[slot.request.rid]
-                res.slot = slot.index
-                res.admitted_s, res.first_token_s = t0, t1
-                tok = int(first_np[i])
-                res.tokens.append(tok)
-                slot_index = slot.index
-                reason = sched.record_token(slot, tok)
-                if reason is not None:
-                    res.finish_s, res.finish_reason = t1, reason
-                    if self._paged is not None:
-                        self._free_paged_slot(slot_index)
+            steps.append(StepRecord("prefill", t0, t1, rids, len(rids),
+                                    iter=self._iter, rows=kp, width=bucket,
+                                    used=used))
+            with TraceAnnotation("serve.emit"):
+                for i, slot in enumerate(slots):
+                    res = results[slot.request.rid]
+                    res.slot = slot.index
+                    res.admitted_s, res.first_token_s = t0, t1
+                    tok = int(first_np[i])
+                    res.tokens.append(tok)
+                    slot_index = slot.index
+                    reason = sched.record_token(slot, tok)
+                    if reason is not None:
+                        res.finish_s, res.finish_reason = t1, reason
+                        if self._paged is not None:
+                            self._free_paged_slot(slot_index)
 
     def _start_chunked(self, admitted, results) -> None:
         """Begin chunked prefill for newly admitted slots: rewind
@@ -708,42 +762,48 @@ class ServeEngine:
         for (bucket, npre), entries in sorted(groups.items()):
             kp = self.n_slots
             pre_len = npre * self.block_size
-            t0 = self.clock()
-            self._sample_power(ts, ws)   # bracket the chunk window
-            tokens = np.zeros((kp, bucket), np.int32)
-            last = np.zeros((kp,), np.int32)
-            slot_ids = np.full((kp,), self.n_slots, np.int32)
-            pre_blocks = np.zeros((kp, npre), np.int32)
-            for i, (slot, start, end) in enumerate(entries):
-                prompt = np.asarray(slot.request.prompt, np.int32)
-                tokens[i, :end - start] = prompt[start:end]
-                last[i] = end - start - 1
-                slot_ids[i] = slot.index
+            used = sum(end - start for _slot, start, end in entries)
+            with TraceAnnotation("serve.prefill", step=len(steps),
+                                 iter=self._iter, rows=kp, bucket=bucket,
+                                 npre=npre, used=used):
+                t0 = self.clock()
+                self._sample_power(ts, ws)   # bracket the chunk window
+                tokens = np.zeros((kp, bucket), np.int32)
+                last = np.zeros((kp,), np.int32)
+                slot_ids = np.full((kp,), self.n_slots, np.int32)
+                pre_blocks = np.zeros((kp, npre), np.int32)
+                for i, (slot, start, end) in enumerate(entries):
+                    prompt = np.asarray(slot.request.prompt, np.int32)
+                    tokens[i, :end - start] = prompt[start:end]
+                    last[i] = end - start - 1
+                    slot_ids[i] = slot.index
+                    if npre:
+                        pre_blocks[i] = self._paged.block_ids(slot.index,
+                                                              pre_len)
                 if npre:
-                    pre_blocks[i] = self._paged.block_ids(slot.index,
-                                                          pre_len)
-            if npre:
-                first, rows = self._prefix_prefill_fn(bucket, npre)(
-                    self.params, self.caches, jnp.asarray(tokens),
-                    jnp.asarray(last), jnp.asarray(pre_blocks))
-            else:
-                first, rows = self._serve_prefill(self.params,
-                                                  jnp.asarray(tokens),
-                                                  jnp.asarray(last))
-            nbk = -(-bucket // self.block_size)
-            blocks = np.full((kp, nbk), self._paged.n_blocks, np.int32)
-            for i, (slot, start, end) in enumerate(entries):
-                own = self._paged.block_ids(slot.index, end)[npre:]
-                blocks[i, :len(own)] = own
-            self.caches = insert_paged_prefill(
-                self.caches, rows, jnp.asarray(blocks),
-                jnp.asarray(slot_ids), block_size=self.block_size)
+                    first, rows = self._prefix_prefill_fn(bucket, npre)(
+                        self.params, self.caches, jnp.asarray(tokens),
+                        jnp.asarray(last), jnp.asarray(pre_blocks))
+                else:
+                    first, rows = self._cold_prefill_fn(bucket)(
+                        self.params, jnp.asarray(tokens), jnp.asarray(last))
+                nbk = -(-bucket // self.block_size)
+                blocks = np.full((kp, nbk), self._paged.n_blocks, np.int32)
+                for i, (slot, start, end) in enumerate(entries):
+                    own = self._paged.block_ids(slot.index, end)[npre:]
+                    blocks[i, :len(own)] = own
+                self.caches = insert_paged_prefill(
+                    self.caches, rows, jnp.asarray(blocks),
+                    jnp.asarray(slot_ids), block_size=self.block_size)
             finals = [(i, slot)
                       for i, (slot, _s, end) in enumerate(entries)
                       if end == slot.prefill_target]
             emitting = [(i, s) for i, s in finals
                         if not s.request.n_replay]
-            first_np = np.asarray(first) if emitting else None
+            first_np = None
+            if emitting:
+                with TraceAnnotation("serve.prefill.fetch"):
+                    first_np = np.asarray(first)
             t1 = self.clock()
             self._sample_power(ts, ws)
             rids = tuple(s.request.rid for s, _s, _e in entries)
@@ -751,39 +811,42 @@ class ServeEngine:
             # n_tokens counts only the first tokens actually emitted,
             # keeping the credited-token accounting exact
             steps.append(StepRecord("prefill", t0, t1, rids,
-                                    len(emitting)))
-            for slot, _start, end in entries:
-                slot.prefill_pos = end
-                slot.pos = end
-            for i, slot in finals:
-                req = slot.request
-                if req.n_replay:
-                    # resume: the original prompt is back in cache; the
-                    # emitted tail now replays through the decode program
-                    # as forced inputs (this chunk's argmax is a prefill
-                    # recompute of an already-emitted token — discard it,
-                    # decode's version is the stream's ground truth). No
-                    # prefix registration either: the tail blocks would
-                    # hold decode-built KV, and an adopter's phased twin
-                    # would prefill them — bit-divergence by adoption.
-                    slot.replay = req.n_replay
-                    slot.last_token = int(req.prompt[slot.prefill_pos])
-                    continue
-                if self.prefix_cache:
-                    self.prefix_stats["registered_blocks"] += \
-                        self._paged.prefix_register(
-                            slot.index, [int(t) for t in req.prompt])
-                res = results[req.rid]
-                # a resume that had already emitted keeps its stamp
-                if res.first_token_s == 0.0:
-                    res.first_token_s = t1
-                tok = int(first_np[i])
-                res.tokens.append(tok)
-                slot_index = slot.index
-                reason = sched.record_token(slot, tok)
-                if reason is not None:
-                    res.finish_s, res.finish_reason = t1, reason
-                    self._free_paged_slot(slot_index)
+                                    len(emitting), fetched=bool(emitting),
+                                    iter=self._iter, rows=kp, width=bucket,
+                                    used=used))
+            with TraceAnnotation("serve.emit"):
+                for slot, _start, end in entries:
+                    slot.prefill_pos = end
+                    slot.pos = end
+                for i, slot in finals:
+                    req = slot.request
+                    if req.n_replay:
+                        # resume: the original prompt is back in cache; the
+                        # emitted tail now replays through the decode program
+                        # as forced inputs (this chunk's argmax is a prefill
+                        # recompute of an already-emitted token — discard it,
+                        # decode's version is the stream's ground truth). No
+                        # prefix registration either: the tail blocks would
+                        # hold decode-built KV, and an adopter's phased twin
+                        # would prefill them — bit-divergence by adoption.
+                        slot.replay = req.n_replay
+                        slot.last_token = int(req.prompt[slot.prefill_pos])
+                        continue
+                    if self.prefix_cache:
+                        self.prefix_stats["registered_blocks"] += \
+                            self._paged.prefix_register(
+                                slot.index, [int(t) for t in req.prompt])
+                    res = results[req.rid]
+                    # a resume that had already emitted keeps its stamp
+                    if res.first_token_s == 0.0:
+                        res.first_token_s = t1
+                    tok = int(first_np[i])
+                    res.tokens.append(tok)
+                    slot_index = slot.index
+                    reason = sched.record_token(slot, tok)
+                    if reason is not None:
+                        res.finish_s, res.finish_reason = t1, reason
+                        self._free_paged_slot(slot_index)
 
     def _decode_plan(self, sched: Scheduler, active,
                      admission_blocked: bool = False,
@@ -832,102 +895,116 @@ class ServeEngine:
                           steps, ts, ws, allow_preempt: bool = False):
         """Dispatch ``k`` decode steps with the token stream chained on
         device, then drain all outputs in one batched fetch."""
-        if self.cache_kind == "paged":
-            if allow_preempt:
-                # chunked mode: growth past the optimistic reservation
-                # evicts younger slots on CacheOOM; grow oldest-first so
-                # eviction flows old -> young, then drop evicted slots
-                for s in sorted(active,
-                                key=lambda s: (s.request.arrival_s
-                                               if s.request else 0.0,
-                                               s.index)):
-                    if not s.decoding:
-                        continue   # preempted by an older slot's growth
-                    self._ensure_with_preempt(sched, s, s.pos + k,
-                                              results)
-                active = [s for s in active if s.decoding]
-                if not active:
-                    return
+        span = TraceAnnotation("serve.decode", step=len(steps),
+                               iter=self._iter)
+        with span:
+            nb = used = 0
+            if self.cache_kind == "paged":
+                if allow_preempt:
+                    # chunked mode: growth past the optimistic reservation
+                    # evicts younger slots on CacheOOM; grow oldest-first
+                    # so eviction flows old -> young, then drop evicted
+                    # slots
+                    for s in sorted(active,
+                                    key=lambda s: (s.request.arrival_s
+                                                   if s.request else 0.0,
+                                                   s.index)):
+                        if not s.decoding:
+                            continue   # preempted by an older slot's growth
+                        self._ensure_with_preempt(sched, s, s.pos + k,
+                                                  results)
+                    active = [s for s in active if s.decoding]
+                    if not active:
+                        return
+                else:
+                    for s in active:
+                        self._paged.ensure(s.index, s.pos + k)
+                if self.prefix_cache:
+                    # copy-on-write net: decode writes land at pos >=
+                    # prompt_len, past every registered (full,
+                    # block-aligned) prefix block, so this is structurally
+                    # a no-op today — but if a shared block ever ends up
+                    # under a write cursor, it is copied out here instead
+                    # of corrupting every other reader of that block.
+                    srcs: list = []
+                    dsts: list = []
+                    for s in active:
+                        sc, dc = self._paged.make_writable(s.index, s.pos, k)
+                        srcs += sc
+                        dsts += dc
+                    if srcs:
+                        self.caches = copy_blocks(
+                            self.caches, jnp.asarray(srcs, jnp.int32),
+                            jnp.asarray(dsts, jnp.int32))
+                tables = self._paged.device_tables()
+                nb = self._nb_bucket(self._paged.max_owned())
+                step = self._paged_step_fn(nb)
+                extra = (tables,)
+                # micro-step i attends the blocks of pos + i + 1 tokens
+                bs = self.block_size
+                used = sum(min((s.pos + i) // bs + 1, nb)
+                           for s in active for i in range(k))
             else:
-                for s in active:
-                    self._paged.ensure(s.index, s.pos + k)
-            if self.prefix_cache:
-                # copy-on-write net: decode writes land at pos >=
-                # prompt_len, past every registered (full, block-aligned)
-                # prefix block, so this is structurally a no-op today —
-                # but if a shared block ever ends up under a write
-                # cursor, it is copied out here instead of corrupting
-                # every other reader of that block.
-                srcs: list = []
-                dsts: list = []
-                for s in active:
-                    sc, dc = self._paged.make_writable(s.index, s.pos, k)
-                    srcs += sc
-                    dsts += dc
-                if srcs:
-                    self.caches = copy_blocks(
-                        self.caches, jnp.asarray(srcs, jnp.int32),
-                        jnp.asarray(dsts, jnp.int32))
-            tables = self._paged.device_tables()
-            step = self._paged_step_fn(self._nb_bucket(self._paged.max_owned()))
-            extra = (tables,)
-        else:
-            step = self._serve_step
-            extra = ()
-        tok = jnp.asarray(sched.input_tokens())
-        pos0 = sched.positions()
-        adv = sched.active_mask().astype(np.int32)  # idle rows stay parked
-        rids = tuple(s.request.rid for s in active)
-        t0 = self.clock()
-        self._sample_power(ts, ws)   # bracket the decode window
-        outs = []
-        caches = self.caches
-        for i in range(k):
-            tok, caches = step(self.params, tok, caches,
-                               jnp.asarray(pos0 + i * adv), *extra)
-            try:
-                tok.copy_to_host_async()
-            except AttributeError:   # older jax array types
-                pass
-            outs.append(tok)
-        self.caches = caches
-        outs_np = [np.asarray(o) for o in outs]   # pipeline drain: one sync
+                step = self._serve_step
+                extra = ()
+            span.set_metadata(k=k, nb=nb, rows=self.n_slots, used=used)
+            tok = jnp.asarray(sched.input_tokens())
+            pos0 = sched.positions()
+            adv = sched.active_mask().astype(np.int32)  # idle rows stay parked
+            rids = tuple(s.request.rid for s in active)
+            t0 = self.clock()
+            self._sample_power(ts, ws)   # bracket the decode window
+            outs = []
+            caches = self.caches
+            for i in range(k):
+                tok, caches = step(self.params, tok, caches,
+                                   jnp.asarray(pos0 + i * adv), *extra)
+                try:
+                    tok.copy_to_host_async()
+                except AttributeError:   # older jax array types
+                    pass
+                outs.append(tok)
+            self.caches = caches
+        with TraceAnnotation("serve.decode.fetch"):
+            outs_np = [np.asarray(o) for o in outs]  # pipeline drain: 1 sync
         t1 = self.clock()
         self._sample_power(ts, ws)
         if self.watchdog is not None:
             self.watchdog.observe(self._decode_idx, (t1 - t0) / k)
         self._decode_idx += 1
         emitted = 0
-        for out in outs_np:
-            for s in active:
-                if s.request is None:     # finished at an earlier micro-step
-                    continue
-                if s.replay:
-                    # preemption-resume replay: this step consumed a
-                    # forced emitted-tail token. Mid-replay outputs are
-                    # discarded (the stream already has them); the LAST
-                    # replay step's argmax is the next NEW token and
-                    # falls through to the normal emission path.
-                    s.replay -= 1
-                    if s.replay:
-                        s.pos += 1
-                        s.last_token = int(s.request.prompt[s.pos])
+        with TraceAnnotation("serve.emit"):
+            for out in outs_np:
+                for s in active:
+                    if s.request is None:  # finished at an earlier micro-step
                         continue
-                res = results[s.request.rid]
-                tok_i = int(out[s.index])
-                emitted += 1
-                res.tokens.append(tok_i)
-                slot_index = s.index
-                reason = sched.record_token(s, tok_i)
-                if reason is not None:
-                    res.finish_s, res.finish_reason = t1, reason
-                    if self._paged is not None:
-                        self._free_paged_slot(slot_index)
+                    if s.replay:
+                        # preemption-resume replay: this step consumed a
+                        # forced emitted-tail token. Mid-replay outputs are
+                        # discarded (the stream already has them); the LAST
+                        # replay step's argmax is the next NEW token and
+                        # falls through to the normal emission path.
+                        s.replay -= 1
+                        if s.replay:
+                            s.pos += 1
+                            s.last_token = int(s.request.prompt[s.pos])
+                            continue
+                    res = results[s.request.rid]
+                    tok_i = int(out[s.index])
+                    emitted += 1
+                    res.tokens.append(tok_i)
+                    slot_index = s.index
+                    reason = sched.record_token(s, tok_i)
+                    if reason is not None:
+                        res.finish_s, res.finish_reason = t1, reason
+                        if self._paged is not None:
+                            self._free_paged_slot(slot_index)
         # rids credit every stepped slot with the window's energy
         # (replay steps burn compute too); n_tokens counts only tokens
         # actually appended to a stream, so token accounting stays exact
         steps.append(StepRecord("decode", t0, t1, rids * k, emitted,
-                                n_steps=k))
+                                n_steps=k, iter=self._iter,
+                                rows=self.n_slots, width=nb, used=used))
 
     # ------------------------------------------------------------------
     # Warmup (compile outside any measured window)
@@ -1081,108 +1158,116 @@ class ServeEngine:
 
         poll = 0
         while sched.has_work:
-            now_rel = self.clock() - t_start
-            # -- graceful degradation: deadline expiry + overload caps ----
-            if has_deadlines or faults is not None:
-                sched._absorb_arrivals(now_rel)
-                if has_deadlines:
-                    for req in sched.shed_expired(now_rel):
-                        _mark_shed(req)
-                cap = faults.queue_cap_at(poll) if faults is not None \
-                    else None
-                if cap is not None:
-                    for req in sched.shed_newest(cap):
-                        _mark_shed(req)
-            poll += 1
-            # -- admission: prefill newly admitted requests ---------------
-            # a headroom-deferred head retries only once free_blocks has
-            # moved — not every loop iteration (re-admit/unadmit churn)
-            if self._admission_blocked():
-                admitted = []
-            else:
-                if not self._scripted:
-                    self._defer_free_blocks = None
-                admitted = sched.refill(now_rel)
-                if admitted and not self._scripted \
-                        and self.cache_kind == "paged":
-                    admitted = self._admit_paged(sched, admitted, results,
-                                                 chunked=chunked)
-            if chunked:
-                if admitted:
-                    self._start_chunked(admitted, results)
-                if any(s.prefilling for s in sched.slots):
-                    self._model_prefill_chunks(sched, results, steps,
-                                               ts, ws, ct)
-            elif admitted and not self._scripted:
-                self._model_prefill_admitted(sched, admitted, results,
-                                             steps, ts, ws)
-            elif admitted:
-                for slot in admitted:
-                    req = slot.request
-                    res = results[req.rid]
-                    res.slot = slot.index
-                    res.admitted_s = self.clock()
-                    self._sample_power(ts, ws)   # bracket the prefill window
-                    first = self._slot_prefill(slot.index, req.prompt)
+            self._iter = poll
+            with TraceAnnotation("serve.iter", iter=poll):
+                with TraceAnnotation("serve.admit"):
+                    now_rel = self.clock() - t_start
+                    # -- graceful degradation: deadline expiry + overload
+                    if has_deadlines or faults is not None:
+                        sched._absorb_arrivals(now_rel)
+                        if has_deadlines:
+                            for req in sched.shed_expired(now_rel):
+                                _mark_shed(req)
+                        cap = faults.queue_cap_at(poll) \
+                            if faults is not None else None
+                        if cap is not None:
+                            for req in sched.shed_newest(cap):
+                                _mark_shed(req)
+                    poll += 1
+                    # -- admission: prefill newly admitted requests -------
+                    # a headroom-deferred head retries only once
+                    # free_blocks has moved — not every loop iteration
+                    # (re-admit/unadmit churn)
+                    if self._admission_blocked():
+                        admitted = []
+                    else:
+                        if not self._scripted:
+                            self._defer_free_blocks = None
+                        admitted = sched.refill(now_rel)
+                        if admitted and not self._scripted \
+                                and self.cache_kind == "paged":
+                            admitted = self._admit_paged(
+                                sched, admitted, results, chunked=chunked)
+                    if chunked and admitted:
+                        self._start_chunked(admitted, results)
+                if chunked:
+                    if any(s.prefilling for s in sched.slots):
+                        self._model_prefill_chunks(sched, results, steps,
+                                                   ts, ws, ct)
+                elif admitted and not self._scripted:
+                    self._model_prefill_admitted(sched, admitted, results,
+                                                 steps, ts, ws)
+                elif admitted:
+                    for slot in admitted:
+                        req = slot.request
+                        res = results[req.rid]
+                        res.slot = slot.index
+                        res.admitted_s = self.clock()
+                        self._sample_power(ts, ws)   # bracket the window
+                        first = self._slot_prefill(slot.index, req.prompt)
+                        t1 = self.clock()
+                        self._sample_power(ts, ws)
+                        res.first_token_s = t1
+                        res.tokens.append(int(first))
+                        steps.append(StepRecord("prefill", res.admitted_s,
+                                                t1, (req.rid,), 1,
+                                                iter=self._iter))
+                        reason = sched.record_token(slot, int(first))
+                        if reason is not None:
+                            res.finish_s, res.finish_reason = t1, reason
+                # -- decode over all fully-prefilled slots ----------------
+                active = sched.decode_slots()
+                if (faults is not None and active
+                        and faults.slot_fault_at(self._decode_idx)):
+                    # injected slot failure: evict the YOUNGEST decoding
+                    # slot (never the oldest — FIFO degradation). The
+                    # victim re-queues at the front and resumes via decode
+                    # replay, so its stream stays bit-identical to a
+                    # fault-free run.
+                    victim = max(active, key=lambda s: (s.request.arrival_s,
+                                                        s.request.rid))
+                    self._preempt_slot(sched, victim, results)
+                    self.injected_faults += 1
+                    active = sched.decode_slots()
+                prefilling = any(s.prefilling for s in sched.slots)
+                if active and not self._scripted:
+                    k = self._decode_plan(
+                        sched, active,
+                        admission_blocked=self._admission_blocked(),
+                        prefilling=prefilling)
+                    self._model_decode_run(sched, active, k, results,
+                                           steps, ts, ws,
+                                           allow_preempt=chunked)
+                elif active:
+                    rids = tuple(s.request.rid for s in active)
+                    t0 = self.clock()
+                    self._sample_power(ts, ws)   # bracket the decode window
+                    out = self._slot_decode(sched.input_tokens(),
+                                            sched.positions(),
+                                            sched.active_mask())
                     t1 = self.clock()
                     self._sample_power(ts, ws)
-                    res.first_token_s = t1
-                    res.tokens.append(int(first))
-                    steps.append(StepRecord("prefill", res.admitted_s, t1,
-                                            (req.rid,), 1))
-                    reason = sched.record_token(slot, int(first))
-                    if reason is not None:
-                        res.finish_s, res.finish_reason = t1, reason
-            # -- decode over all fully-prefilled slots --------------------
-            active = sched.decode_slots()
-            if (faults is not None and active
-                    and faults.slot_fault_at(self._decode_idx)):
-                # injected slot failure: evict the YOUNGEST decoding slot
-                # (never the oldest — FIFO degradation). The victim
-                # re-queues at the front and resumes via decode replay,
-                # so its stream stays bit-identical to a fault-free run.
-                victim = max(active, key=lambda s: (s.request.arrival_s,
-                                                    s.request.rid))
-                self._preempt_slot(sched, victim, results)
-                self.injected_faults += 1
-                active = sched.decode_slots()
-            prefilling = any(s.prefilling for s in sched.slots)
-            if active and not self._scripted:
-                k = self._decode_plan(
-                    sched, active,
-                    admission_blocked=self._admission_blocked(),
-                    prefilling=prefilling)
-                self._model_decode_run(sched, active, k, results,
-                                       steps, ts, ws,
-                                       allow_preempt=chunked)
-            elif active:
-                rids = tuple(s.request.rid for s in active)
-                t0 = self.clock()
-                self._sample_power(ts, ws)   # bracket the decode window
-                out = self._slot_decode(sched.input_tokens(),
-                                        sched.positions(),
-                                        sched.active_mask())
-                t1 = self.clock()
-                self._sample_power(ts, ws)
-                if watchdog is not None:
-                    watchdog.observe(self._decode_idx, t1 - t0)
-                self._decode_idx += 1
-                steps.append(StepRecord("decode", t0, t1, rids, len(rids)))
-                out = np.asarray(out)
-                for s in active:
-                    res = results[s.request.rid]
-                    tok = int(out[s.index])
-                    res.tokens.append(tok)
-                    reason = sched.record_token(s, tok)
-                    if reason is not None:
-                        res.finish_s, res.finish_reason = t1, reason
-            elif sched.n_pending and not prefilling:
-                # idle: nothing admitted yet — wait for the next arrival
-                nxt = sched.next_arrival_s()
-                wait = (t_start + nxt) - self.clock() if nxt is not None \
-                    else poll_s
-                if wait > 0:
-                    self.sleep_fn(min(wait, 0.05))
+                    if watchdog is not None:
+                        watchdog.observe(self._decode_idx, t1 - t0)
+                    self._decode_idx += 1
+                    steps.append(StepRecord("decode", t0, t1, rids,
+                                            len(rids), iter=self._iter))
+                    out = np.asarray(out)
+                    for s in active:
+                        res = results[s.request.rid]
+                        tok = int(out[s.index])
+                        res.tokens.append(tok)
+                        reason = sched.record_token(s, tok)
+                        if reason is not None:
+                            res.finish_s, res.finish_reason = t1, reason
+                elif sched.n_pending and not prefilling:
+                    # idle: nothing admitted yet — wait for the next arrival
+                    nxt = sched.next_arrival_s()
+                    wait = (t_start + nxt) - self.clock() \
+                        if nxt is not None else poll_s
+                    if wait > 0:
+                        with TraceAnnotation("serve.wait"):
+                            self.sleep_fn(min(wait, 0.05))
 
         self._sample_power(ts, ws)
         out_results = sorted(results.values(), key=lambda r: r.finish_s)

@@ -94,6 +94,23 @@ class StepRecord:
     ``n_steps`` is the number of decode micro-steps the window fused
     (1 for prefill and legacy single-step decode) — the denominator for
     per-step occupancy: ``n_tokens / (n_steps * n_slots)``.
+
+    ``fetched`` is True where ``t1`` was taken after the window's results
+    came back to the host (the first-token or drain fetch; a scripted
+    engine's synchronous call), so ``[t0, t1]`` bounds the window's
+    device work. A non-final prefill chunk emits nothing and fetches
+    nothing: its ``t1`` only follows the dispatch, and its device work
+    runs on into later windows.
+
+    The model paths also count the work of the program call:
+    ``iter`` is the serve loop iteration that ran it; ``rows`` the padded
+    batch of the call; ``width`` the prompt bucket in tokens (prefill)
+    or the gathered block-table columns ``nb`` (paged decode); ``used``
+    the real prompt tokens of the call (prefill), or for a paged decode
+    window the blocks holding live KV that its micro-steps attend,
+    summed over micro-steps and live slots, each capped at ``width``.
+    ``rows``, ``width`` and ``used`` stay 0 where there is no such
+    program (scripted engines, slotted decode).
     """
 
     kind: str             # "prefill" | "decode"
@@ -102,6 +119,11 @@ class StepRecord:
     rids: tuple
     n_tokens: int
     n_steps: int = 1
+    fetched: bool = True
+    iter: int = 0
+    rows: int = 0
+    width: int = 0
+    used: int = 0
 
     @property
     def duration_s(self) -> float:

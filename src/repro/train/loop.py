@@ -1,6 +1,12 @@
 """Training loop with the fault-tolerance features the paper's scale needs:
 auto-resume from the latest checkpoint, async periodic checkpointing,
 straggler watchdog, power measurement hooks, throughput accounting.
+
+Each step runs inside ``jax.profiler.StepTraceAnnotation("train")`` with
+the children ``train.batch`` (the step's batch), ``train.dispatch`` (the
+step call), ``train.fetch`` (the loss to the host, the step's one sync),
+``train.hooks`` and ``train.ckpt``: a profiler trace puts the device's
+idle time between steps down to one of them.
 """
 from __future__ import annotations
 
@@ -10,6 +16,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.ckpt.checkpoint import (CheckpointManager, latest_step,
                                    latest_valid_step, restore_resilient)
@@ -87,41 +94,48 @@ def train_loop(train_step: Callable, params: Params, opt_state: Params,
     step = start_step
     n_run = 0
     for step in range(start_step, cfg.total_steps):
-        batch = get_batch(step)
-        t0 = time.perf_counter()
-        params, opt_state, metrics = train_step(params, opt_state, batch)
-        loss = float(metrics["loss"])
-        if faults is not None:
-            slow = faults.slowdown_s(step + 1)
-            if slow > 0:
-                sleep_fn(slow)  # inside the timed region: watchdog sees it
-        dt = time.perf_counter() - t0
-        watchdog.observe(step, dt)
-        losses.append(loss)
-        n_run += 1
-        if hooks:
-            for h in hooks:
-                h(step, metrics, dt)
-        if cfg.log_every and (step + 1) % cfg.log_every == 0:
-            tps = tokens_per_s(cfg.global_batch, cfg.seq_len, dt)
-            print(f"  step {step + 1}/{cfg.total_steps} loss={loss:.4f} "
-                  f"({dt * 1e3:.0f} ms, {tps:,.0f} tok/s)")
-        if mgr is not None and (step + 1) % cfg.ckpt_every == 0:
-            mgr.save_async((params, opt_state), step + 1)
-        if fail_at_step is not None and step + 1 >= fail_at_step:
-            if mgr is not None:
-                mgr.wait()
-            raise InjectedCrash(step + 1)
-        if faults is not None:
-            ev = faults.crash_at(step + 1)
-            if ev is not None:
+        with StepTraceAnnotation("train", step_num=step):
+            with TraceAnnotation("train.batch"):
+                batch = get_batch(step)
+            t0 = time.perf_counter()
+            with TraceAnnotation("train.dispatch"):
+                params, opt_state, metrics = train_step(params, opt_state,
+                                                        batch)
+            with TraceAnnotation("train.fetch"):
+                loss = float(metrics["loss"])
+            if faults is not None:
+                slow = faults.slowdown_s(step + 1)
+                if slow > 0:
+                    sleep_fn(slow)  # inside the timed region: watchdog sees it
+            dt = time.perf_counter() - t0
+            watchdog.observe(step, dt)
+            losses.append(loss)
+            n_run += 1
+            if hooks:
+                with TraceAnnotation("train.hooks"):
+                    for h in hooks:
+                        h(step, metrics, dt)
+            if cfg.log_every and (step + 1) % cfg.log_every == 0:
+                tps = tokens_per_s(cfg.global_batch, cfg.seq_len, dt)
+                print(f"  step {step + 1}/{cfg.total_steps} loss={loss:.4f} "
+                      f"({dt * 1e3:.0f} ms, {tps:,.0f} tok/s)")
+            if mgr is not None and (step + 1) % cfg.ckpt_every == 0:
+                with TraceAnnotation("train.ckpt"):
+                    mgr.save_async((params, opt_state), step + 1)
+            if fail_at_step is not None and step + 1 >= fail_at_step:
                 if mgr is not None:
-                    mgr.wait()  # the crash lands after any in-flight save
-                if ev.kind == "ckpt_corrupt" and cfg.ckpt_dir:
-                    corrupt_checkpoint(cfg.ckpt_dir)
-                if ev.kind == "device_loss":
-                    raise DeviceLoss(step + 1, ev.n)
+                    mgr.wait()
                 raise InjectedCrash(step + 1)
+            if faults is not None:
+                ev = faults.crash_at(step + 1)
+                if ev is not None:
+                    if mgr is not None:
+                        mgr.wait()  # the crash lands after any in-flight save
+                    if ev.kind == "ckpt_corrupt" and cfg.ckpt_dir:
+                        corrupt_checkpoint(cfg.ckpt_dir)
+                    if ev.kind == "device_loss":
+                        raise DeviceLoss(step + 1, ev.n)
+                    raise InjectedCrash(step + 1)
     if mgr is not None:
         mgr.save_sync((params, opt_state), cfg.total_steps)
         mgr.wait()
