@@ -16,10 +16,13 @@ TPU-native design (mirrors ``flash_attention.py``):
     ``(1, bs, Kh, Dh)``: its last two dims equal the pool's, which is
     what Mosaic requires of a block whose head slice (1 row) is not a
     multiple of 8. The kernel walks the heads in a static loop;
-  - the block table and true lengths ride in as **scalar prefetch**
-    arguments (``pltpu.PrefetchScalarGridSpec``): the k/v BlockSpec
-    index map reads ``tables[b, j]`` to DMA the *physical* pool block —
-    the paged indirection costs one SMEM lookup, not a gather;
+  - the block table, true lengths and the layer ride in as **scalar
+    prefetch** arguments (``pltpu.PrefetchScalarGridSpec``): the k/v
+    BlockSpec index map reads ``(layer, tables[b, j])`` to DMA the
+    *physical* pool block of that layer straight out of the stacked
+    ``(L, n_blocks, ...)`` pool — the paged indirection costs one SMEM
+    lookup, not a gather, and the decode program never slices a layer's
+    pool out (it updates the stacked pool in place);
   - GQA is expressed in the q layout: q is viewed as ``(B, Kh, G, Dh)``
     so the ``G = H // Kh`` query heads sharing a KV head are one MXU
     operand; repeated KV is never materialized;
@@ -29,10 +32,12 @@ TPU-native design (mirrors ``flash_attention.py``):
     positional (``kpos < length``), with the optional sliding window
     applied the same way as the slotted path;
   - int8 KV pools dequantize inside the load: per-block-per-head
-    symmetric scales ``(n_blocks, Kh)`` ride in through SMEM, one
-    block's ``(1, 1, Kh)`` row per grid step addressed by the same table
-    lookup, and ``k * scale`` happens on the VMEM tile — fp KV is never
-    materialized anywhere.
+    symmetric scales ``(L, n_blocks, Kh)`` ride in through SMEM, one
+    ``(8, Kh)`` tile of rows per grid step (the least Mosaic takes)
+    addressed by the same layer and table lookup, and ``k * scale``
+    happens on the VMEM tile — fp KV is never materialized anywhere, and
+    the stacked scales reach the kernel in their own shape, with no
+    relayout.
 
 Validated against ``kernels.ref.paged_decode_attention_ref`` in
 interpret mode (tests sweep block sizes, GQA groups, ragged lengths and
@@ -49,10 +54,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+#: pool blocks per int8 scale tile: one grid step DMAs the (8, Kh) rows
+#: of scales that hold its block's row
+SCALE_ROWS = 8
 
 
-def _decode_kernel(tables_ref, lengths_ref, *refs, scale: float, bs: int,
-                   kh: int, nb: int, window: Optional[int], quantized: bool):
+def _decode_kernel(tables_ref, lengths_ref, layer_ref, *refs, scale: float,
+                   bs: int, kh: int, nb: int, rows: int,
+                   window: Optional[int], quantized: bool):
     if quantized:
         (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -72,6 +81,8 @@ def _decode_kernel(tables_ref, lengths_ref, *refs, scale: float, bs: int,
     if window is not None:
         run = jnp.logical_and(run, (j + 1) * bs - 1 > length - 1 - window)
 
+    row = tables_ref[b, j] % rows             # the block's scale row
+
     @pl.when(run)
     def _compute():
         for h in range(kh):       # static unroll over the block's KV heads
@@ -79,8 +90,8 @@ def _decode_kernel(tables_ref, lengths_ref, *refs, scale: float, bs: int,
             k = k_ref[0, :, h, :].astype(jnp.float32)            # (bs, Dh)
             v = v_ref[0, :, h, :].astype(jnp.float32)
             if quantized:
-                k = k * ksc_ref[0, 0, h]
-                v = v * vsc_ref[0, 0, h]
+                k = k * ksc_ref[row, h]
+                v = v * vsc_ref[row, h]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
             kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             mask = kpos < length
@@ -104,18 +115,27 @@ def _decode_kernel(tables_ref, lengths_ref, *refs, scale: float, bs: int,
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                            tables: jax.Array, lengths: jax.Array, *,
+                           layer=None,
                            window: Optional[int] = None,
                            scale: Optional[float] = None,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, Dh); k/v_pool: (n_blocks, bs, Kh, Dh); tables: (B, nb)
-    int32 physical block ids; lengths: (B,) int32 KV length per sequence
-    including the current token. ``k_scale``/``v_scale`` (n_blocks, Kh)
-    f32 mark an int8 pool — blocks dequantize on their VMEM tile, fp KV
-    is never materialized. Returns (B, H, Dh)."""
+    """q: (B, H, Dh); k/v_pool: (L, n_blocks, bs, Kh, Dh) read at
+    ``layer`` (an int32 scalar, traced or not), or (n_blocks, bs, Kh, Dh),
+    one layer; tables: (B, nb) int32 physical block ids; lengths: (B,)
+    int32 KV length per sequence including the current token.
+    ``k_scale``/``v_scale`` ((L,) n_blocks, Kh) f32 mark an int8 pool —
+    blocks dequantize on their VMEM tile, fp KV is never materialized.
+    Returns (B, H, Dh)."""
+    if k_pool.ndim == 4:              # one layer: a leading unit axis
+        assert layer is None, layer
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
     b, h, dh = q.shape
-    n_blocks, bs, kh = k_pool.shape[:3]
+    n_blocks, bs, kh = k_pool.shape[1:4]
     assert h % kh == 0, (h, kh)
     assert (k_scale is None) == (v_scale is None)
     g = h // kh
@@ -123,34 +143,36 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     quantized = k_scale is not None
 
+    rows = min(n_blocks, SCALE_ROWS)
     kernel = functools.partial(_decode_kernel, scale=scale, bs=bs, kh=kh,
-                               nb=nb, window=window, quantized=quantized)
+                               nb=nb, rows=rows, window=window,
+                               quantized=quantized)
 
-    def kv_index(bi, j, tables_ref, lengths_ref):
-        return (tables_ref[bi, j], 0, 0, 0)
+    def kv_index(bi, j, tables_ref, lengths_ref, layer_ref):
+        return (layer_ref[0], tables_ref[bi, j], 0, 0, 0)
 
-    def scale_index(bi, j, tables_ref, lengths_ref):
-        return (tables_ref[bi, j], 0, 0)
+    def scale_index(bi, j, tables_ref, lengths_ref, layer_ref):
+        return (layer_ref[0], tables_ref[bi, j] // rows, 0)
 
-    def q_index(bi, j, tables_ref, lengths_ref):
+    def q_index(bi, j, tables_ref, lengths_ref, layer_ref):
         return (bi, 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, kh, g, dh), q_index),
-        pl.BlockSpec((1, bs, kh, dh), kv_index),
-        pl.BlockSpec((1, bs, kh, dh), kv_index),
+        pl.BlockSpec((None, 1, bs, kh, dh), kv_index),
+        pl.BlockSpec((None, 1, bs, kh, dh), kv_index),
     ]
     operands = [q.reshape(b, kh, g, dh), k_pool, v_pool]
     if quantized:
-        # (n_blocks, 1, Kh): the last two block dims then equal the
-        # array's, and SMEM holds one block's scales per grid step
-        in_specs += [pl.BlockSpec((1, 1, kh), scale_index,
+        # a block of 8 rows: a (1, Kh) block would need the scales
+        # reshaped to (L, n_blocks, 1, Kh), a relayout of every layer's
+        # scales each step; the kernel picks its block's row
+        in_specs += [pl.BlockSpec((None, rows, kh), scale_index,
                                   memory_space=pltpu.SMEM)] * 2
-        operands += [s.astype(jnp.float32).reshape(n_blocks, 1, kh)
-                     for s in (k_scale, v_scale)]
+        operands += [s.astype(jnp.float32) for s in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kh, g, dh), q_index),
@@ -165,5 +187,6 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dh), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out.reshape(b, h, dh)

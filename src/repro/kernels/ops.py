@@ -9,7 +9,9 @@ Selection map (who runs what, where):
   flash_attention        prefill/train attention; ``impl="pallas"`` on
                          TPU, ``impl="xla"`` (ref) on CPU.
   paged_decode_attention the serve decode hot path over a paged KV pool
-                         (``serve.cache.PagedKVCache`` block tables).
+                         (``serve.cache.PagedKVCache`` block tables),
+                         read at one layer of the stacked pool that
+                         the decode step updates in place.
                          ``impl="xla"`` gathers the table into a dense
                          view (the CPU path); ``impl="pallas"`` walks
                          the table with scalar-prefetch DMA — the TPU
@@ -68,23 +70,29 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 @partial(jax.jit, static_argnames=("window", "impl", "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
-                           window: Optional[int] = None, impl: str = "pallas",
-                           interpret: bool = False, k_scale=None,
-                           v_scale=None):
+                           layer=None, window: Optional[int] = None,
+                           impl: str = "pallas", interpret: bool = False,
+                           k_scale=None, v_scale=None):
     """Single-token GQA decode over a paged KV pool.
 
-    q: (B, H, Dh); k/v_pool: (n_blocks, bs, Kh, Dh); tables: (B, nb)
-    int32 physical block ids (position order, trash block 0 for unowned
-    columns); lengths: (B,) int32 KV length incl. the current token.
-    k/v_scale: optional (n_blocks, Kh) f32 int8-pool scales.
+    q: (B, H, Dh); k/v_pool: (L, n_blocks, bs, Kh, Dh) read at the int32
+    scalar ``layer``, or (n_blocks, bs, Kh, Dh) with ``layer`` None;
+    tables: (B, nb) int32 physical block ids (position order, trash
+    block 0 for unowned columns); lengths: (B,) int32 KV length incl.
+    the current token. k/v_scale: optional ((L,) n_blocks, Kh) f32
+    int8-pool scales.
     """
     if impl == "xla":
+        if layer is not None:
+            k_pool, v_pool = k_pool[layer], v_pool[layer]
+            if k_scale is not None:
+                k_scale, v_scale = k_scale[layer], v_scale[layer]
         return ref.paged_decode_attention_ref(q, k_pool, v_pool, tables,
                                               lengths, window=window,
                                               k_scale=k_scale,
                                               v_scale=v_scale)
     return _paged_decode_pallas(q, k_pool, v_pool, tables, lengths,
-                                window=window, k_scale=k_scale,
+                                layer=layer, window=window, k_scale=k_scale,
                                 v_scale=v_scale, interpret=interpret)
 
 

@@ -341,26 +341,28 @@ def prefill_attention(c: ModelConfig, p: Params, x: jax.Array, *,
 
 
 def _quantized_block_write(pool: jax.Array, scale: jax.Array,
-                           new: jax.Array, blk: jax.Array, off: jax.Array):
-    """Write one token (B, Kh, Dh) into int8 pool blocks at
-    ``(blk[b], off[b])``, preserving the per-(block, head) symmetric
-    scale invariant: dequantize the owning block, place the token,
-    re-quantize under ``max(old_scale, maxabs(new)/127)``. The scale is
-    MONOTONE, so when the new token fits the old range the block's other
-    int8 codes are bit-unchanged (round(i*s/s) == i). Duplicate ``blk``
-    entries only ever occur on the trash block 0 (idle slots), where the
-    undefined scatter order is harmless."""
+                           new: jax.Array, layer: jax.Array, blk: jax.Array,
+                           off: jax.Array):
+    """Write one token (B, Kh, Dh) into the int8 pool blocks
+    ``(layer, blk[b])`` at offset ``off[b]`` of the stacked pool
+    (L, n_blocks, bs, Kh, Dh), preserving the per-(block, head)
+    symmetric scale invariant: dequantize the owning block, place the
+    token, re-quantize under ``max(old_scale, maxabs(new)/127)``. The
+    scale is MONOTONE, so when the new token fits the old range the
+    block's other int8 codes are bit-unchanged (round(i*s/s) == i).
+    Duplicate ``blk`` entries only ever occur on the trash block 0 (idle
+    slots), where the undefined scatter order is harmless."""
     newf = new.astype(jnp.float32)
-    osc = jnp.take(scale, blk, axis=0).astype(jnp.float32)       # (B, Kh)
-    deq = jnp.take(pool, blk, axis=0).astype(jnp.float32) \
+    osc = scale[layer, blk].astype(jnp.float32)                  # (B, Kh)
+    deq = pool[layer, blk].astype(jnp.float32) \
         * osc[:, None, :, None]                                  # (B,bs,Kh,Dh)
     rows = jnp.arange(new.shape[0])
     deq = deq.at[rows, off].set(newf)
     nsc = jnp.maximum(osc, jnp.max(jnp.abs(newf), axis=-1) / 127.0)
     q = jnp.round(deq / jnp.where(nsc > 0.0, nsc, 1.0)[:, None, :, None])
     q = jnp.clip(q, -127, 127).astype(pool.dtype)
-    return (pool.at[blk].set(q, mode="drop"),
-            scale.at[blk].set(nsc.astype(scale.dtype), mode="drop"))
+    return (pool.at[layer, blk].set(q, mode="drop"),
+            scale.at[layer, blk].set(nsc.astype(scale.dtype), mode="drop"))
 
 
 def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
@@ -371,7 +373,8 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
                      paged_impl: str = "xla",
                      paged_interpret: bool = False,
                      cache_k_scale: Optional[jax.Array] = None,
-                     cache_v_scale: Optional[jax.Array] = None):
+                     cache_v_scale: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None):
     """One-token decode against a fixed-size KV cache.
 
     x: (B, 1, D); cache_k/v: (B, T, Kh, Dh); pos: scalar int32 (step
@@ -385,21 +388,24 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
     attends to all cached positions <= pos under a (per-row) mask
     (O(T) per step — linear, not quadratic).
 
-    Paged path (``block_tables`` given): cache_k/v are *shared block
-    pools* ``(n_blocks, bs, Kh, Dh)`` and ``block_tables`` is the
+    Paged path (``block_tables`` given): cache_k/v are the *stacked
+    shared block pools* ``(L, n_blocks, bs, Kh, Dh)`` of every layer,
+    ``layer`` the int32 index of this one, and ``block_tables`` the
     ``(B, max_blocks)`` per-slot table (``serve.cache.PagedKVCache``).
-    The new token is scattered into its slot's current block; attention
-    walks only the first ``n_kv_blocks`` (static — the engine buckets it
-    to the longest live slot) table columns via
+    The new token is scattered into its slot's current block of this
+    layer (one scatter at ``[layer, blk, off]``, in place: the pool is
+    never sliced per layer); attention walks only the first
+    ``n_kv_blocks`` (static — the engine buckets it to the longest live
+    slot) table columns of the layer via
     ``kernels.ops.paged_decode_attention``, masked by true per-slot
     length — never the ``max_len``-padded row. ``pos`` must be the
     per-slot vector; idle slots park at a position whose table column is
-    the trash block 0.
+    the trash block 0. The new stacked pools are returned.
 
-    ``cache_k_scale``/``cache_v_scale`` (n_blocks, Kh) f32 mark an int8
-    pool: the token write goes through :func:`_quantized_block_write`
-    and the return value grows to a 5-tuple
-    ``(out, cache_k, cache_v, cache_k_scale, cache_v_scale)``.
+    ``cache_k_scale``/``cache_v_scale`` (L, n_blocks, Kh) f32 mark an
+    int8 pool: the token write goes through
+    :func:`_quantized_block_write` and the return value grows to a
+    5-tuple ``(out, cache_k, cache_v, cache_k_scale, cache_v_scale)``.
     """
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
@@ -410,7 +416,7 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
     if block_tables is not None:
         assert per_slot, "paged decode requires per-slot positions"
         from repro.kernels import ops as _kops
-        bs_blk = cache_k.shape[1]
+        bs_blk = cache_k.shape[2]
         nb = n_kv_blocks if n_kv_blocks is not None else block_tables.shape[1]
         blk = jnp.take_along_axis(block_tables, pos[:, None] // bs_blk,
                                   axis=1)[:, 0]
@@ -418,20 +424,19 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
         quantized = cache_k_scale is not None
         if quantized:
             cache_k, cache_k_scale = _quantized_block_write(
-                cache_k, cache_k_scale, k_new[:, 0], blk, off)
+                cache_k, cache_k_scale, k_new[:, 0], layer, blk, off)
             cache_v, cache_v_scale = _quantized_block_write(
-                cache_v, cache_v_scale, v_new[:, 0], blk, off)
+                cache_v, cache_v_scale, v_new[:, 0], layer, blk, off)
         else:
-            cache_k = cache_k.at[blk, off].set(
+            cache_k = cache_k.at[layer, blk, off].set(
                 k_new[:, 0].astype(cache_k.dtype), mode="drop")
-            cache_v = cache_v.at[blk, off].set(
+            cache_v = cache_v.at[layer, blk, off].set(
                 v_new[:, 0].astype(cache_v.dtype), mode="drop")
-        cache_k = _hint(cache_k, "cache_spec")
-        cache_v = _hint(cache_v, "cache_spec")
         out = _kops.paged_decode_attention(
             q[:, 0], cache_k, cache_v, block_tables[:, :nb], pos + 1,
-            window=c.attn_window, impl=paged_impl, interpret=paged_interpret,
-            k_scale=cache_k_scale, v_scale=cache_v_scale)
+            layer=layer, window=c.attn_window, impl=paged_impl,
+            interpret=paged_interpret, k_scale=cache_k_scale,
+            v_scale=cache_v_scale)
         out = out_proj(p, out[:, None].astype(q.dtype))
         if quantized:
             return out, cache_k, cache_v, cache_k_scale, cache_v_scale

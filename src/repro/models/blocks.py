@@ -262,60 +262,62 @@ def stack_decode(c: ModelConfig, layers: Params, x: jax.Array, caches: Params,
 
     ``block_tables`` selects the paged KV path: attention k/v cache
     leaves are shared block pools and every layer reads the same
-    ``(B, max_blocks)`` table (see ``attention.decode_attention``);
-    SSM/conv state leaves stay per-slot rows in either layout.
+    ``(B, max_blocks)`` table (see ``attention.decode_attention``). The
+    stacked pools (k, v and an int8 pool's k_scale/v_scale, leading axis
+    n_periods) ride the layer scan as its carry, and each layer writes
+    its token into them at the scanned layer index: sliced out as xs and
+    stacked back as ys, every pool would be copied whole each step.
+    SSM/conv state leaves stay per-slot rows, scanned as xs/ys, in either
+    layout.
     """
     kinds = slot_kinds(c)
+    pooled = {f"slot{i}" for i, (mixer, _) in enumerate(kinds)
+              if block_tables is not None and mixer == "attn"}
+    pools = {n: caches[n] for n in pooled}
+    rows = {n: v for n, v in caches.items() if n not in pooled}
+    layer_ids = None
+    if pooled:
+        layer_ids = jnp.arange(jax.tree.leaves(layers)[0].shape[0],
+                               dtype=jnp.int32)
 
-    def body(x, inp):
-        if enc_kv_stacked is not None:
-            period_params, cache, ekv = inp
-        else:
-            (period_params, cache), ekv = inp, None
-        new_cache = {}
+    def body(carry, inp):
+        x, pools = carry
+        period_params, cache, ekv, layer = inp
+        new_pools, new_rows = {}, {}
         for i, (mixer, ffn) in enumerate(kinds):
-            sp = period_params[f"slot{i}"]
-            sc = cache[f"slot{i}"]
+            name = f"slot{i}"
+            sp = period_params[name]
             h = apply_norm(c, sp["norm1"], x)
             if mixer == "attn":
-                if "k_scale" in sc:
-                    h, ck, cv, ksc, vsc = attn.decode_attention(
-                        c, sp["attn"], h, sc["k"], sc["v"], pos, impl=impl,
-                        block_tables=block_tables, n_kv_blocks=n_kv_blocks,
-                        paged_impl=paged_impl,
-                        paged_interpret=paged_interpret,
-                        cache_k_scale=sc["k_scale"],
-                        cache_v_scale=sc["v_scale"])
-                    new_cache[f"slot{i}"] = {"k": ck, "v": cv,
-                                             "k_scale": ksc, "v_scale": vsc}
-                else:
-                    h, ck, cv = attn.decode_attention(
-                        c, sp["attn"], h, sc["k"], sc["v"], pos, impl=impl,
-                        block_tables=block_tables, n_kv_blocks=n_kv_blocks,
-                        paged_impl=paged_impl,
-                        paged_interpret=paged_interpret)
-                    new_cache[f"slot{i}"] = {"k": ck, "v": cv}
+                sc = pools[name] if name in pools else cache[name]
+                h, *kv = attn.decode_attention(
+                    c, sp["attn"], h, sc["k"], sc["v"], pos, impl=impl,
+                    block_tables=block_tables, n_kv_blocks=n_kv_blocks,
+                    paged_impl=paged_impl, paged_interpret=paged_interpret,
+                    cache_k_scale=sc.get("k_scale"),
+                    cache_v_scale=sc.get("v_scale"), layer=layer)
+                out = new_pools if name in pools else new_rows
+                out[name] = dict(zip(("k", "v", "k_scale", "v_scale"), kv))
             else:
+                sc = cache[name]
                 h, conv_s, ssm_s = ssm_mod.mamba_decode(c, sp["mamba"], h,
                                                         sc["conv"], sc["ssm"])
-                new_cache[f"slot{i}"] = {"conv": conv_s, "ssm": ssm_s}
+                new_rows[name] = {"conv": conv_s, "ssm": ssm_s}
             x = x + h
             if ekv is not None:
                 hx = apply_norm(c, sp["norm_x"], x)
                 x = x + attn.cross_attention(
                     c, sp["cross"], hx,
-                    (ekv[f"slot{i}"]["k"], ekv[f"slot{i}"]["v"]), impl=impl)
+                    (ekv[name]["k"], ekv[name]["v"]), impl=impl)
             if ffn == "mlp":
                 x = x + apply_mlp(c, sp["mlp"], apply_norm(c, sp["norm2"], x))
             elif ffn == "moe":
                 y, _ = moe_mod.moe_forward(c, sp["moe"],
                                            apply_norm(c, sp["norm2"], x))
                 x = x + y
-        return x, new_cache
+        return (x, new_pools), new_rows
 
-    if enc_kv_stacked is None:
-        x, new_caches = jax.lax.scan(body, x, (layers, caches), unroll=unroll)
-    else:
-        x, new_caches = jax.lax.scan(body, x, (layers, caches, enc_kv_stacked),
-                                     unroll=unroll)
-    return x, new_caches
+    (x, pools), rows = jax.lax.scan(
+        body, (x, pools), (layers, rows, enc_kv_stacked, layer_ids),
+        unroll=unroll)
+    return x, {**rows, **pools}

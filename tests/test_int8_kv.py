@@ -89,35 +89,38 @@ def test_prefill_kernel_int8_matches_int8_ref():
 
 def test_quantized_block_write_monotone_scale():
     rng = np.random.default_rng(2)
-    n, bs, kh, dh = 4, 8, 2, 16
-    pool, scale = _quantize_pool(
-        jnp.asarray(rng.normal(size=(n, bs, kh, dh)) * 3.0, jnp.float32))
+    n_layers, n, bs, kh, dh = 2, 4, 8, 2, 16
+    pool, scale = jax.vmap(_quantize_pool)(jnp.asarray(
+        rng.normal(size=(n_layers, n, bs, kh, dh)) * 3.0, jnp.float32))
+    layer = jnp.asarray(1, jnp.int32)
     blk = jnp.asarray([1, 2], jnp.int32)
     off = jnp.asarray([5, 2], jnp.int32)
 
     # a token well inside the blocks' existing range: scale unchanged,
-    # every OTHER code in the written blocks bit-identical
+    # every OTHER code in the written blocks bit-identical, and the
+    # other layer untouched
     small = jnp.asarray(rng.uniform(-0.5, 0.5, size=(2, kh, dh)), jnp.float32)
-    p1, s1 = _quantized_block_write(pool, scale, small, blk, off)
+    p1, s1 = _quantized_block_write(pool, scale, small, layer, blk, off)
     assert np.array_equal(np.asarray(s1), np.asarray(scale))
+    assert np.array_equal(np.asarray(p1[0]), np.asarray(pool[0]))
     for i, (b_, o_) in enumerate(zip([1, 2], [5, 2])):
-        old = np.asarray(pool[b_])
-        new = np.asarray(p1[b_])
+        old = np.asarray(pool[1, b_])
+        new = np.asarray(p1[1, b_])
         mask = np.ones(bs, bool)
         mask[o_] = False
         assert np.array_equal(new[mask], old[mask])
-        deq = new[o_] * np.asarray(s1[b_])[:, None]
+        deq = new[o_] * np.asarray(s1[1, b_])[:, None]
         assert np.abs(deq - np.asarray(small[i])).max() \
-            <= np.asarray(s1[b_]).max() / 2.0 + 1e-7
+            <= np.asarray(s1[1, b_]).max() / 2.0 + 1e-7
 
     # a token OUTSIDE the range grows the scale; it never shrinks
     big = jnp.full((2, kh, dh), 50.0, jnp.float32)
-    p2, s2 = _quantized_block_write(p1, s1, big, blk, off)
+    p2, s2 = _quantized_block_write(p1, s1, big, layer, blk, off)
     assert (np.asarray(s2) >= np.asarray(s1) - 1e-9).all()
-    assert (np.asarray(jnp.take(s2, blk, 0)) >
-            np.asarray(jnp.take(s1, blk, 0))).all()
-    deq = np.asarray(p2[1, 5], np.float32) * np.asarray(s2[1])[:, None]
-    assert np.abs(deq - 50.0).max() <= np.asarray(s2[1]).max() / 2.0 + 1e-7
+    assert (np.asarray(s2[1, blk]) > np.asarray(s1[1, blk])).all()
+    assert np.array_equal(np.asarray(s2[0]), np.asarray(s1[0]))
+    deq = np.asarray(p2[1, 1, 5], np.float32) * np.asarray(s2[1, 1])[:, None]
+    assert np.abs(deq - 50.0).max() <= np.asarray(s2[1, 1]).max() / 2.0 + 1e-7
 
 
 def _pool_leaves(caches, suffix=""):

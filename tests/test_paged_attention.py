@@ -27,7 +27,10 @@ SWEEP = [
     (6, 3, 16, 32, (2, 90, 31), None, jnp.float32),        # odd group count
     (4, 2, 16, 16, (50, 96, 3), 24, jnp.float32),          # windowed
     (2, 1, 64, 16, (33,), 16, jnp.bfloat16),               # windowed bf16
+    (8, 2, 64, 16, (17, 40, 1), None, jnp.int8),           # int8 pool
 ]
+#: pool layers of the stacked cases (the decode program's (L, ...) pool)
+N_LAYERS = 3
 
 
 def _tol(dt):
@@ -60,18 +63,73 @@ def _paged_setup(lengths, bs, kh, dh, dt, seed=0, max_len=96):
             jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)))
 
 
-@pytest.mark.parametrize("h,kh,dh,bs,lengths,window,dt", SWEEP)
-def test_paged_kernel_matches_oracle(h, kh, dh, bs, lengths, window, dt):
+def _quantize(pool):
+    """(n_blocks, bs, Kh, Dh) fp -> (int8 codes, (n_blocks, Kh) f32
+    per-block-per-head symmetric scales), as the int8 pool holds them."""
+    sc = jnp.max(jnp.abs(pool), axis=(1, 3)) / 127.0
+    q = jnp.round(pool / jnp.where(sc > 0.0, sc, 1.0)[:, None, :, None])
+    return jnp.clip(q, -127, 127).astype(jnp.int8), sc
+
+
+def _stack(pool, layer, seed):
+    """``pool`` as layer ``layer`` of an (N_LAYERS, ...) stack whose other
+    layers hold other values."""
+    rng = np.random.default_rng(seed)
+    layers = [jnp.asarray(rng.normal(size=pool.shape), pool.dtype)
+              for _ in range(N_LAYERS)]
+    layers[layer] = pool
+    return jnp.stack(layers)
+
+
+def _oracle_cases():
+    """Each sweep row on a one-layer pool (ids as the sweep gives them),
+    then read as the first and the last layer of a stacked pool."""
+    for layer in (None, 0, N_LAYERS - 1):
+        for i, row in enumerate(SWEEP):
+            h, kh, dh, bs, _lengths, window, dt = row
+            at = "" if layer is None else f"-layer{layer}"
+            yield pytest.param(*row, layer, id=f"{h}-{kh}-{dh}-{bs}-lengths{i}"
+                               f"-{window}-{dt.__name__}{at}")
+
+
+@pytest.mark.parametrize("h,kh,dh,bs,lengths,window,dt,layer",
+                         list(_oracle_cases()))
+def test_paged_kernel_matches_oracle(h, kh, dh, bs, lengths, window, dt,
+                                     layer):
+    """The kernel on a one-layer pool, and on a stacked
+    (L, n_blocks, bs, Kh, Dh) pool read at ``layer``: the stacked read
+    equals the one-layer kernel on ``pool[layer]`` bit for bit, and both
+    match the oracle. int8 pools carry (n_blocks, Kh) scales, stacked
+    alike."""
     rng = np.random.default_rng(1)
     b = len(lengths)
-    q = jnp.asarray(rng.normal(size=(b, h, dh)), jnp.float32).astype(dt)
-    _, _, k_pool, v_pool, tables, lens = _paged_setup(lengths, bs, kh, dh, dt)
+    quantized = dt == jnp.int8
+    fdt = jnp.bfloat16 if quantized else dt
+    q = jnp.asarray(rng.normal(size=(b, h, dh)), jnp.float32).astype(fdt)
+    _, _, k_pool, v_pool, tables, lens = _paged_setup(lengths, bs, kh, dh,
+                                                      fdt)
+    k_sc = v_sc = None
+    if quantized:
+        (k_pool, k_sc), (v_pool, v_sc) = _quantize(k_pool), _quantize(v_pool)
     want = ref.paged_decode_attention_ref(q, k_pool, v_pool, tables, lens,
-                                          window=window)
+                                          window=window, k_scale=k_sc,
+                                          v_scale=v_sc)
     got = paged_decode_attention(q, k_pool, v_pool, tables, lens,
-                                 window=window, interpret=True)
+                                 window=window, k_scale=k_sc, v_scale=v_sc,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **_tol(dt))
+                               np.asarray(want, np.float32), **_tol(fdt))
+    if layer is None:
+        return
+    stk = [_stack(x, layer, seed) for seed, x in enumerate(
+        (k_pool, v_pool) if k_sc is None else (k_pool, v_pool, k_sc, v_sc))]
+    k_sc, v_sc = stk[2:] if quantized else (None, None)
+    got_l = paged_decode_attention(q, stk[0], stk[1], tables, lens,
+                                   layer=jnp.int32(layer), window=window,
+                                   k_scale=k_sc, v_scale=v_sc,
+                                   interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_l, np.float32),
+                                  np.asarray(got, np.float32))
 
 
 @pytest.mark.parametrize("h,kh,dh,bs,lengths,window,dt", SWEEP[:4])

@@ -153,6 +153,67 @@ def test_paged_insert_then_decode_matches_slotted(setup):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_paged_decode_writes_only_the_token_rows(setup):
+    """One paged decode step over a random stacked pool: each layer's
+    pool comes back equal to the input but for the 16 (slot, layer)
+    token rows at ``[blk, off]`` (8 slots x 2 layers), each holding the
+    token's K/V as the slotted decode writes it; every other block is
+    bit-unchanged, and the idle slots' rows land in the trash block 0.
+    The live slots' logits equal the slotted decode's over the same
+    history."""
+    c, params = setup
+    n_slots, live = 8, (7, 16, 33, 1, 50, 20)         # 2 idle slots
+    mb = MAX_LEN // BS
+    n_blocks = 1 + n_slots * mb
+    rng = np.random.default_rng(11)
+    paged = PagedKVCache(c, n_slots, MAX_LEN, params, block_size=BS)
+    assert paged.caches["slot0"]["k"].shape[:2] == (c.n_layers, n_blocks)
+    pool = jax.tree.map(lambda l: jnp.asarray(rng.normal(size=l.shape),
+                                              l.dtype), paged.caches)
+    tables = np.zeros((n_slots, mb), np.int32)
+    free = rng.permutation(np.arange(1, n_blocks))
+    pos = np.zeros(n_slots, np.int32)
+    for s, ln in enumerate(live):           # ln tokens held, one more due
+        n = ln // BS + 1
+        tables[s, :n], free = free[:n], free[n:]
+        pos[s] = ln
+    pos[6], pos[7] = MAX_LEN - 1, MAX_LEN - 2  # idle: trash column
+    tok = jnp.asarray(rng.integers(0, c.vocab, (n_slots, 1)), jnp.int32)
+    logits, out = lm.decode_step(c, params, tok, pool, jnp.asarray(pos),
+                                 block_tables=jnp.asarray(tables),
+                                 n_kv_blocks=mb)
+
+    # the slotted cache holding each live slot's history from the pool
+    dense = {"slot0": {kv: jnp.asarray(np.asarray(pool["slot0"][kv])[
+        :, tables].reshape(c.n_layers, n_slots, MAX_LEN, c.n_kv_heads,
+                           c.d_head)) for kv in ("k", "v")}}
+    want_logits, want = lm.decode_step(c, params, tok, dense,
+                                       jnp.asarray(pos))
+    rows = list(range(len(live)))
+    np.testing.assert_allclose(np.asarray(logits)[rows],
+                               np.asarray(want_logits)[rows],
+                               rtol=2e-5, atol=2e-5)
+    written = 0
+    for kv in ("k", "v"):
+        before = np.asarray(pool["slot0"][kv])
+        after = np.asarray(out["slot0"][kv])
+        expect = before.copy()
+        for layer in range(c.n_layers):
+            for s in range(n_slots):
+                blk, off = tables[s, pos[s] // BS], pos[s] % BS
+                assert (blk == 0) == (s >= len(live))
+                row = after[layer, blk, off]
+                assert not np.array_equal(row, before[layer, blk, off])
+                if blk:
+                    np.testing.assert_allclose(
+                        row, np.asarray(want["slot0"][kv])[layer, s, pos[s]],
+                        rtol=2e-5, atol=2e-5)
+                expect[layer, blk, off] = row
+                written += 1
+        np.testing.assert_array_equal(after, expect)
+    assert written == 2 * 16
+
+
 # ---------------------------------------------------------------------------
 # Engine-level: layouts and fused windows are invisible in token streams
 # ---------------------------------------------------------------------------
