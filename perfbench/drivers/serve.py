@@ -12,7 +12,8 @@ at the mix's fixed rate; it ends when the last request has finished.
 Each request is timed from its due time: time to first token, the mean
 gap between its output tokens, and output tokens over the run. ``correct``
 compares, for a seeded sample of the finished requests (the longest among
-them), every served token against the plain float32 reference run over
+them), every served token against the configuration's plain float32
+reference (``model.reference_of``) run over
 the prompt and the served tokens: the widest gap by which a served
 token's reference logit lies below the reference's best at that position.
 """
@@ -25,8 +26,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from perfbench import stats, traffic
-from perfbench.model import model_config
-from perfbench.reference import model as ref_model
+from perfbench.model import model_config, reference_of
+
+#: what this driver calls of the configuration's plain reference
+REFERENCE_CALLS = ("make_weights", "sequence_logits")
 
 
 def make_engine(ctx, c, params):
@@ -83,23 +86,23 @@ def warm(ctx, engine) -> None:
 
 
 def served_gaps(cfg, params, results, max_len: int, quant=None) -> list:
-    """Per request, the gap of each served token under the float32
-    reference: reference best logit minus the served token's. With
+    """Per request, the gap of each served token under the configuration's
+    float32 reference: reference best logit minus the served token's. With
     ``quant`` the served tokens are replaced by the argmax of the
     reference computed at that precision (the control)."""
+    ref = reference_of(cfg)
     out = []
     for r in results:
         seq = np.concatenate([r["prompt"], np.asarray(r["tokens"], np.int32)])
         pad = np.zeros((1, max_len), np.int32)
         pad[0, :len(seq)] = seq
         lo, n = len(r["prompt"]) - 1, len(r["tokens"])
-        lg = ref_model.sequence_logits(cfg, params, jnp.asarray(pad))[0]
+        lg = ref.sequence_logits(cfg, params, jnp.asarray(pad))[0]
         lg = np.asarray(lg[lo:lo + n])
         if quant is None:
             tok = np.asarray(r["tokens"])
         else:
-            lq = ref_model.sequence_logits(cfg, params, jnp.asarray(pad),
-                                           quant)[0]
+            lq = ref.sequence_logits(cfg, params, jnp.asarray(pad), quant)[0]
             tok = np.asarray(jnp.argmax(lq[lo:lo + n], -1))
         out.append(lg.max(-1) - lg[np.arange(n), tok])
     return out
@@ -128,10 +131,11 @@ def serve_window(ctx, engine, reqs):
 
 def run(ctx) -> dict:
     cfg, mix = ctx.cfg, ctx.mix
+    ref = reference_of(cfg)
     c = model_config(cfg)
     from repro.models import lm
     with ctx.span("bench.build"):
-        params = ref_model.make_weights(lm.init_abstract(c), ctx.seed)
+        params = ref.make_weights(lm.init_abstract(c), ctx.seed)
         engine = make_engine(ctx, c, params)
         warm(ctx, engine)
     specs = traffic.generate(mix, ctx.seed, ctx.seconds, cfg["vocab_size"])

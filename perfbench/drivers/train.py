@@ -14,8 +14,9 @@ readings for ``correct`` are taken in those first steps:
   from the float32 master copy, before step 4 takes it.
 
 After the window the state is freed and the plain reference
-(``reference/train.py``) repeats the three steps from the same weights and
-rows; the gaps are the numbers compared.
+(``reference/train.py``, on the loss and gradient of the reference that
+the configuration names) repeats the three steps from the same weights
+and rows; the gaps are the numbers compared.
 
 The driver runs one chip; a data-parallel mix needs a driver of its own.
 """
@@ -29,9 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench.model import model_config
-from perfbench.reference import model as ref_model
+from perfbench.model import model_config, reference_of
 from perfbench.reference import train as ref_train
+from perfbench.reference.model import _paths
+
+#: what this driver calls of the configuration's plain reference
+REFERENCE_CALLS = ("make_weights", "loss_and_grad")
 
 #: correctness readings are taken over this many leading steps
 N_CHECK = 3
@@ -79,10 +83,10 @@ def change_norms(names: list, master, w0: list) -> dict:
             for n, m, w in zip(names, jax.tree_util.tree_leaves(master), w0)}
 
 
-def build(ctx, c, rows):
-    """The compiled step, its state and the feed: one object, as the
-    window will use it. Returns (step, params, opt_state, data, abstract,
-    oc)."""
+def build(ctx, c, rows, reference):
+    """The compiled step, its state (weights from ``reference``) and the
+    feed: one object, as the window will use it. Returns (step, params,
+    opt_state, data, abstract, oc)."""
     from repro.models import lm
     from repro.train.optimizer import OptConfig, opt_init
     from repro.train.step import StepConfig, make_train_step
@@ -94,7 +98,7 @@ def build(ctx, c, rows):
                     remat=mix["remat"])
     abstract = lm.init_abstract(c)
     gb = mix["global_batch"]
-    params = ref_model.make_weights(abstract, ctx.seed)
+    params = reference.make_weights(abstract, ctx.seed)
     opt_state = jax.jit(partial(opt_init, oc))(params)
     step = jax.jit(make_train_step(c, oc, sc), donate_argnums=(0, 1))
 
@@ -108,13 +112,15 @@ def build(ctx, c, rows):
 def run(ctx) -> dict:
     from repro.train.loop import LoopConfig, train_loop
     cfg, mix = ctx.cfg, ctx.mix
+    reference = reference_of(cfg)
     c = model_config(cfg)
     rows = make_rows(cfg, mix, ctx.seed)
     gb, seq = mix["global_batch"], mix["seq_len"]
     with ctx.span("bench.build"):
-        step, params, opt_state, data, abstract, oc = build(ctx, c, rows)
+        step, params, opt_state, data, abstract, oc = build(ctx, c, rows,
+                                                            reference)
         # the initial weights, on the host: what the change is taken from
-        names = ref_model._paths(abstract)[0]
+        names = _paths(abstract)[0]
         w0 = jax.device_get(jax.tree_util.tree_leaves(params))
     readings: dict = {"loss": []}
     calls = {"n": 0}
@@ -175,7 +181,8 @@ def run(ctx) -> dict:
 
     t_ref = time.perf_counter()
     batches = [batch_of(rows, gb, i) for i in range(N_CHECK)]
-    ref = ref_train.reference_readings(cfg, mix, names, w0, batches)
+    ref = ref_train.reference_readings(reference.loss_and_grad, cfg, mix,
+                                       names, w0, batches)
     ctx.notes["reference_s"] = time.perf_counter() - t_ref
     checks = compare(readings, ref, ctx.limits)
     ctx.notes.update(worst_grad_leaf=worst_leaf_gap(readings["grad"],
@@ -202,9 +209,11 @@ def control(ctx, out) -> dict:
     names, w0, batches = out["control_inputs"]
     ref = out["reference"]
     gb = ctx.mix["global_batch"]
-    fp8 = ref_train.reference_readings(ctx.cfg, ctx.mix, names, w0, batches,
-                                       quant="fp8")
-    half = ref_train.reference_readings(ctx.cfg, ctx.mix, names, w0, batches,
+    loss_and_grad = reference_of(ctx.cfg).loss_and_grad
+    fp8 = ref_train.reference_readings(loss_and_grad, ctx.cfg, ctx.mix,
+                                       names, w0, batches, quant="fp8")
+    half = ref_train.reference_readings(loss_and_grad, ctx.cfg, ctx.mix,
+                                        names, w0, batches,
                                         rows=slice(0, gb // 2))
     return {"fp8": compare(fp8, ref, ctx.limits),
             "half_batch": compare(half, ref, ctx.limits)}

@@ -1,6 +1,24 @@
 """Plain float32 reference of the decoder-only transformers the cells run,
 and the benchmark's own weights.
 
+A configuration names its reference under ``"reference"`` (this module,
+``model``, where it names none), and the drivers find it by that name
+(``perfbench.model.reference_of``). A reference is a module of
+``perfbench/reference/`` that provides:
+
+- ``make_weights(abstract, seed, dtype=None, sharding=None)``: the
+  weights, in the program's layout, for a tree of ShapeDtypeStruct, made
+  on the device from ``seed``; every cell's weights come from here;
+- ``sequence_logits(cfg, params, tokens, quant=None)``: float32 logits
+  (1, S, vocab) of one token sequence (1, S), for serving;
+- ``loss_and_grad(cfg, params, batch, quant=None)``: the mean next-token
+  loss of ``batch`` (``tokens``, ``labels``) and its gradient, for
+  training;
+
+with ``quant`` in ``{None, "fp8"}``: ``None`` is the reference, and
+``"fp8"`` is the control. A new reference may import ``ein``, ``_q`` and
+``seed_key`` from here rather than copy them.
+
 Nothing here imports the program. The weights are made by the benchmark
 from the seed, in the layout the program reads (a nested dict of stacked
 per-layer leaves), and handed to both the program and this reference.

@@ -1,7 +1,8 @@
 """Plain reference of the first training steps, in the recipe the
 configuration states (Megatron mixed precision): the model's weights are
 the bfloat16 rounding of float32 master weights; the forward and backward
-passes run in float32 at HIGHEST precision (``model.loss_and_grad``);
+passes run in float32 at HIGHEST precision (the ``loss_and_grad`` of the
+reference the configuration names, passed in);
 global-norm clipping and AdamW with decoupled weight decay under linear
 warmup and cosine decay update the float32 masters.
 
@@ -17,8 +18,6 @@ from concurrent.futures import ThreadPoolExecutor
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from perfbench.reference import model
 
 #: rows of a batch the reference differentiates at once, so that its
 #: float32 logits and attention fit one chip beside the weights
@@ -42,7 +41,7 @@ def _leaf_norms(tree):
         a.astype(jnp.float32)))), tree)
 
 
-def _blocked_loss_and_grad(cfg, w, batch, quant):
+def _blocked_loss_and_grad(loss_and_grad, cfg, w, batch, quant):
     """Mean loss and its gradient over all rows, computed in blocks of
     ``BLOCK_ROWS`` rows (every row has the same number of positions, so
     the mean is the mean of the blocks' means)."""
@@ -51,7 +50,7 @@ def _blocked_loss_and_grad(cfg, w, batch, quant):
     loss, g = 0.0, None
     for s in starts:
         blk = {k: jnp.asarray(x[s:s + BLOCK_ROWS]) for k, x in batch.items()}
-        l_b, g_b = model.loss_and_grad(cfg, w, blk, quant)
+        l_b, g_b = loss_and_grad(cfg, w, blk, quant)
         loss += float(l_b) / len(starts)
         g = g_b if g is None else jax.tree.map(jnp.add, g, g_b)
         del g_b
@@ -75,12 +74,14 @@ def _adam_slice(t: int, hp: dict, g, m, v, w) -> None:
     w -= np.float32(hp["lr"]) * d
 
 
-def reference_readings(cfg: dict, mix: dict, names: list, w0: list,
-                       batches: list, quant=None, rows=None) -> dict:
+def reference_readings(loss_and_grad, cfg: dict, mix: dict, names: list,
+                       w0: list, batches: list, quant=None,
+                       rows=None) -> dict:
     """Losses of the steps of ``batches`` from the initial weights ``w0``
     (host arrays, in the order of ``names``), per-leaf norms of the first
     clipped gradient, and per-leaf norms of the masters' change after all
-    the steps. ``quant="fp8"`` computes every matmul from fp8 operands
+    the steps, the loss and its gradient from ``loss_and_grad(cfg, params,
+    batch, quant)``. ``quant="fp8"`` computes every matmul from fp8 operands
     (the control); ``rows`` keeps only those rows of each batch (a fault
     planted in the reference: part of the batch left out)."""
     opt = mix["optimizer"]
@@ -101,7 +102,8 @@ def reference_readings(cfg: dict, mix: dict, names: list, w0: list,
             # so that the gradient is float32
             w = _f32(_named(names, [jnp.asarray(a.astype(jnp.bfloat16))
                                     for a in master]))
-            loss, g = _blocked_loss_and_grad(cfg, w, batch, quant)
+            loss, g = _blocked_loss_and_grad(loss_and_grad, cfg, w, batch,
+                                             quant)
             del w
             out["loss"].append(loss)
             norms = [float(x) for x in jax.tree_util.tree_leaves(

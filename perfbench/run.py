@@ -4,7 +4,9 @@
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``
 names a configuration (``perfbench/configs/<config>.json``) and a traffic
-mix (``perfbench/mixes/<traffic>.json``); the mix names its driver
+mix (``perfbench/mixes/<traffic>.json``); the configuration names its
+plain reference (``perfbench/reference/<reference>.py``, ``model`` where
+it names none); the mix names its driver
 (``perfbench/drivers/<driver>.py``); the cell's comparison limits are in
 ``perfbench/limits/<cell>.json``; each per-layer metric is read by
 ``perfbench/layers/<metric>.py``.

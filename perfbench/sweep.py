@@ -24,6 +24,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from perfbench import run as R  # noqa: E402
 from perfbench import stats, traffic  # noqa: E402
+from perfbench.model import reference_of  # noqa: E402
 
 
 def fifths(res: list) -> tuple:
@@ -58,7 +59,8 @@ def main(argv=None) -> int:
     ctx = R.Context(files, args.seed, args.seconds, False, 1)
     from repro.models import lm
     c = drv.model_config(ctx.cfg)
-    params = drv.ref_model.make_weights(lm.init_abstract(c), args.seed)
+    params = reference_of(ctx.cfg).make_weights(lm.init_abstract(c),
+                                                args.seed)
     engine = drv.make_engine(ctx, c, params)
     drv.warm(ctx, engine)
     sink = open(args.out, "a") if args.out else None

@@ -36,9 +36,13 @@ BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_each_cell_finds_its_files(cell):
+    from perfbench.model import reference_of
     f = R.cell_files(BENCH, cell)
-    assert f["mix"]["driver"] in ("train", "serve")
-    assert (R.HERE / "drivers" / f"{f['mix']['driver']}.py").exists()
+    path = R.HERE / "drivers" / f"{f['mix']['driver']}.py"
+    assert path.exists()
+    ref = reference_of(f["config"])
+    for fn in R.load_module(path).REFERENCE_CALLS:
+        assert callable(getattr(ref, fn, None)), (ref.__name__, fn)
     e2e, layers = R.cell_metrics(BENCH, cell)
     names = {m["name"] for m in e2e}
     assert "setup_s" in names and len(names) >= 2 and layers
