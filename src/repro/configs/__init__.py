@@ -24,6 +24,9 @@ from repro.configs.jamba_v0_1_52b import CONFIG as _jamba
 from repro.configs.mamba2_1_3b import CONFIG as _mamba2
 from repro.configs.granite_moe_3b_a800m import CONFIG as _granite_moe
 from repro.configs.llama4_maverick_400b_a17b import CONFIG as _llama4
+from repro.configs.granite_4_0_h_small import (
+    CONFIG as _granite_h_small, CONFIG_EP4 as _granite_h_small_ep4,
+)
 
 # The 10 assigned architectures (dry-run + roofline cells).
 ASSIGNED: dict[str, ModelConfig] = {
@@ -47,7 +50,13 @@ PAPER_MODELS: dict[str, ModelConfig] = {
     c.name: c for c in (GPT_117M, GPT_800M, GPT_13B, GPT_175B)
 }
 
-REGISTRY: dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS}
+# Served on the chip beside them: the published model, and one chip's
+# share of its experts under four-way expert parallelism.
+SERVED: dict[str, ModelConfig] = {
+    c.name: c for c in (_granite_h_small, _granite_h_small_ep4)
+}
+
+REGISTRY: dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS, **SERVED}
 
 RESNET_REGISTRY = {
     "resnet50": _resnet50.CONFIG,

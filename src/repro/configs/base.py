@@ -80,9 +80,18 @@ class ModelConfig:
     qkv_bias: bool = False
     mlp_bias: bool = False
     attn_window: Optional[int] = None   # chunked/local attention (tokens)
-    use_rope: bool = True               # False -> learned absolute positions
+    positions: str = "rope"             # "rope" | "learned" (absolute
+    #                                     table) | "none" (NoPE: no table)
     max_position: int = 1 << 20         # for learned positions only
     logits_softcap: float = 0.0
+
+    # scalar multipliers (Granite): embeddings x embed_mult, each residual
+    # branch x resid_mult, attention scores x attn_mult (0 -> 1/sqrt(d_head)),
+    # logits / logits_div. The defaults leave the programs as they were.
+    embed_mult: float = 1.0
+    resid_mult: float = 1.0
+    attn_mult: float = 0.0
+    logits_div: float = 1.0
 
     # norms / activations
     norm: str = "rmsnorm"           # "rmsnorm" | "layernorm"
@@ -94,7 +103,13 @@ class ModelConfig:
     top_k: int = 0
     expert_d_ff: int = 0
     moe_layer_step: int = 1         # every k-th layer is MoE (1 = all)
-    moe_shared: bool = False        # shared expert in parallel with routed
+    shared_d_ff: int = 0            # shared expert's width (0 = none); it
+    #                                 runs in parallel with the routed ones
+    n_experts_held: int = 0         # experts this chip holds: [0, n); 0 = all
+    moe_dispatch: str = "capacity"  # "capacity": per-expert buffers of
+    #   capacity_factor x the fair share (overflow dropped; the experts
+    #   shard over the mesh); "dropless": a grouped matmul over the held
+    #   experts (moe.moe_share_forward)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
 
@@ -126,6 +141,10 @@ class ModelConfig:
     def __post_init__(self):
         if self.d_head == 0:
             object.__setattr__(self, "d_head", self.d_model // max(self.n_heads, 1))
+        assert self.positions in ("rope", "learned", "none"), self.positions
+        assert self.moe_dispatch in ("capacity", "dropless"), self.moe_dispatch
+        assert self.n_experts_held == 0 or self.moe_dispatch == "dropless", (
+            "only the dropless expert layer computes a held share")
 
     # Derived quantities -------------------------------------------------
     @property
@@ -145,6 +164,11 @@ class ModelConfig:
     @property
     def ssm_nheads(self) -> int:
         return self.d_inner // self.ssm_headdim if self.ssm_headdim else 0
+
+    @property
+    def experts_held(self) -> int:
+        """Experts whose weights this chip holds (ids ``[0, held)``)."""
+        return self.n_experts_held or self.n_experts
 
     def is_moe_layer(self, i: int) -> bool:
         if self.n_experts == 0:
@@ -178,7 +202,9 @@ class ModelConfig:
         )
         if self.n_experts:
             small.update(n_experts=min(self.n_experts, 4), expert_d_ff=64,
-                         top_k=min(self.top_k, 2))
+                         top_k=min(self.top_k, 2),
+                         n_experts_held=min(self.n_experts_held, 2),
+                         shared_d_ff=min(self.shared_d_ff, 64))
         if self.ssm_state:
             small.update(ssm_state=16, ssm_headdim=16, ssm_chunk=32)
         if self.attn_layer_period:
@@ -211,9 +237,9 @@ def _mlp_params(c: ModelConfig, d_ff: int) -> int:
 def _mamba_params(c: ModelConfig) -> int:
     di, ns, nh = c.d_inner, c.ssm_state, c.ssm_nheads
     in_proj = c.d_model * (2 * di + 2 * ns + nh)  # z, x, B, C, dt
-    conv = (di + 2 * ns) * c.ssm_conv
+    conv = (di + 2 * ns) * (c.ssm_conv + 1)       # taps and bias
     out = di * c.d_model
-    extras = 2 * nh + di  # A_log, D, norm
+    extras = 3 * nh + di  # dt_bias, A_log, D, norm
     return in_proj + conv + out + extras
 
 
@@ -240,10 +266,10 @@ def _param_count(c: ModelConfig, active_only: bool) -> int:
             pass
         if c.is_moe_layer(i):
             eff = c.expert_d_ff or c.d_ff
-            n_used = c.top_k if active_only else c.n_experts
+            n_used = c.top_k if active_only else c.experts_held
             p += n_used * _mlp_params(c, eff) + per_norm
-            if c.moe_shared:
-                p += _mlp_params(c, eff)
+            if c.shared_d_ff:
+                p += _mlp_params(c, c.shared_d_ff)
             p += c.d_model * c.n_experts  # router
         elif c.d_ff:
             p += _mlp_params(c, c.d_ff) + per_norm

@@ -15,7 +15,7 @@ _COMMON = dict(
     act="gelu",
     qkv_bias=True,
     mlp_bias=True,
-    use_rope=True,        # paper: "rotary positional embeddings"
+    positions="rope",     # paper: "rotary positional embeddings"
     tie_embeddings=True,
 )
 

@@ -23,7 +23,7 @@ CONFIG = ModelConfig(
     top_k=2,
     expert_d_ff=14336,
     moe_layer_step=2,
-    use_rope=False,       # Jamba uses no positional encoding in attn layers
+    positions="none",     # Jamba uses no positional encoding in attn layers
     long_context_ok=True,  # only 4 attention layers; KV seq-sharded
     source="arXiv:2403.19887; hf",
 )

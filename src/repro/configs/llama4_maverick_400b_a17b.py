@@ -22,7 +22,7 @@ CONFIG = ModelConfig(
     top_k=1,
     expert_d_ff=8192,
     moe_layer_step=2,
-    moe_shared=True,      # shared expert in parallel with the routed one
+    shared_d_ff=8192,     # shared expert in parallel with the routed one
     attn_window=8192,     # chunked attention (iRoPE) -> sub-quadratic
     rope_theta=500_000.0,
     long_context_ok=True,

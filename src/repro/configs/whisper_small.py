@@ -17,7 +17,7 @@ CONFIG = ModelConfig(
     act="gelu",
     qkv_bias=True,
     mlp_bias=True,
-    use_rope=False,       # learned absolute positions
+    positions="learned",  # learned absolute positions
     max_position=40960,   # covers decode_32k; long_500k is skipped (quad.)
     tie_embeddings=True,
     source="arXiv:2212.04356; unverified",

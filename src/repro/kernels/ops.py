@@ -68,11 +68,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return out.transpose(0, 2, 1, 3)
 
 
-@partial(jax.jit, static_argnames=("window", "impl", "interpret"))
+@partial(jax.jit, static_argnames=("window", "impl", "interpret", "scale"))
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
                            layer=None, window: Optional[int] = None,
                            impl: str = "pallas", interpret: bool = False,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           scale: Optional[float] = None):
     """Single-token GQA decode over a paged KV pool.
 
     q: (B, H, Dh); k/v_pool: (L, n_blocks, bs, Kh, Dh) read at the int32
@@ -80,7 +81,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
     tables: (B, nb) int32 physical block ids (position order, trash
     block 0 for unowned columns); lengths: (B,) int32 KV length incl.
     the current token. k/v_scale: optional ((L,) n_blocks, Kh) f32
-    int8-pool scales.
+    int8-pool scales. ``scale`` multiplies the scores (None: 1/sqrt(Dh)).
     """
     if impl == "xla":
         if layer is not None:
@@ -89,35 +90,40 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
                 k_scale, v_scale = k_scale[layer], v_scale[layer]
         return ref.paged_decode_attention_ref(q, k_pool, v_pool, tables,
                                               lengths, window=window,
-                                              k_scale=k_scale,
+                                              scale=scale, k_scale=k_scale,
                                               v_scale=v_scale)
     return _paged_decode_pallas(q, k_pool, v_pool, tables, lengths,
-                                layer=layer, window=window, k_scale=k_scale,
-                                v_scale=v_scale, interpret=interpret)
+                                layer=layer, window=window, scale=scale,
+                                k_scale=k_scale, v_scale=v_scale,
+                                interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("window", "impl", "interpret",
-                                   "block_q", "block_k"))
+                                   "block_q", "block_k", "scale"))
 def paged_prefill_attention(q, k_suffix, v_suffix, k_pool, v_pool, tables, *,
                             window: Optional[int] = None,
                             impl: str = "pallas", interpret: bool = False,
                             block_q: int = 128, block_k: int = 128,
-                            k_scale=None, v_scale=None):
+                            k_scale=None, v_scale=None,
+                            scale: Optional[float] = None):
     """Chunk-of-queries causal GQA attention over [paged prefix ++ own
     suffix KV].
 
     q: (B, Sq, H, Dh); k/v_suffix: (B, Sq, Kh, Dh); k/v_pool:
     (n_blocks, bs, Kh, Dh); tables: (B, npre) int32 prefix block ids in
     position order (queries sit at global positions npre*bs + i).
-    k/v_scale: optional (n_blocks, Kh) f32 int8-pool scales.
+    k/v_scale: optional (n_blocks, Kh) f32 int8-pool scales. ``scale``
+    multiplies the scores (None: 1/sqrt(Dh)).
     """
     if impl == "xla":
         return ref.paged_prefill_attention_ref(q, k_suffix, v_suffix,
                                                k_pool, v_pool, tables,
-                                               window=window, k_scale=k_scale,
+                                               window=window, scale=scale,
+                                               k_scale=k_scale,
                                                v_scale=v_scale)
     return _paged_prefill_pallas(q, k_suffix, v_suffix, k_pool, v_pool,
-                                 tables, window=window, k_scale=k_scale,
+                                 tables, window=window, scale=scale,
+                                 k_scale=k_scale,
                                  v_scale=v_scale, block_q=block_q,
                                  block_k=block_k, interpret=interpret)
 

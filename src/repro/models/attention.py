@@ -110,7 +110,7 @@ def qkv_proj(c: ModelConfig, p: Params, x: jax.Array,
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    if c.use_rope and positions is not None:
+    if c.positions == "rope" and positions is not None:
         # barrier: keep the f32 rope math from retroactively upcasting the
         # projection matmuls (and thus the stacked weights) to f32
         q, k = opt_barrier((q, k))
@@ -134,16 +134,26 @@ def make_causal_mask(s: int, t: int, window: Optional[int] = None,
     return m
 
 
+def score_scale(c: ModelConfig) -> Optional[float]:
+    """The configuration's attention multiplier, or None for the usual
+    ``1 / sqrt(d_head)`` (which every attention path computes itself)."""
+    return c.attn_mult or None
+
+
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, mask: Optional[jax.Array],
-         impl: str = "repeat") -> jax.Array:
+         impl: str = "repeat", scale: Optional[float] = None) -> jax.Array:
     """Scaled dot-product attention.
 
     q: (B,S,H,Dh); k,v: (B,T,Kh,Dh); mask: broadcastable to (B,1,S,T) or None
-    (None = full bidirectional). fp32 softmax.
+    (None = full bidirectional). fp32 softmax. ``scale`` multiplies the
+    scores (default ``1 / sqrt(Dh)``).
     """
     b, s, h, dh = q.shape
     kh = k.shape[2]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32)).astype(q.dtype)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32)).astype(q.dtype)
+    else:
+        scale = jnp.asarray(scale, q.dtype)
     q = q * scale
     if impl == "repeat" or h == kh:
         if h != kh:
@@ -180,7 +190,8 @@ Q_CHUNK = 1024
 
 def sdpa_chunked_q(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    causal: bool, window: Optional[int], impl: str,
-                   q_chunk: int = Q_CHUNK, unroll: bool = False) -> jax.Array:
+                   q_chunk: int = Q_CHUNK, unroll: bool = False,
+                   scale: Optional[float] = None) -> jax.Array:
     """Flash-style memory-bounded attention: scan over query chunks.
 
     Each chunk materializes only a (B, H, q_chunk, T_vis) score block —
@@ -201,8 +212,8 @@ def sdpa_chunked_q(q: jax.Array, k: jax.Array, v: jax.Array, *,
             # keys visible to this chunk: [0, start + q_chunk)
             t_vis = t  # static bound; mask handles the tail
             mask = make_causal_mask(q_chunk, t_vis, None, q_offset=start)
-            return _hint(sdpa(qi, k, v, mask[None, None], impl=impl),
-                         "out_spec")
+            return _hint(sdpa(qi, k, v, mask[None, None], impl=impl,
+                              scale=scale), "out_spec")
         if causal and window is not None:
             w = min(window, t)
             vis = min(q_chunk + w, t)
@@ -212,9 +223,10 @@ def sdpa_chunked_q(q: jax.Array, k: jax.Array, v: jax.Array, *,
             qpos = start + jnp.arange(q_chunk)[:, None]
             kpos = k_start + jnp.arange(vis)[None, :]
             mask = (kpos <= qpos) & (kpos > qpos - window)
-            return _hint(sdpa(qi, ks, vs, mask[None, None], impl=impl),
-                         "out_spec")
-        return _hint(sdpa(qi, k, v, None, impl=impl), "out_spec")
+            return _hint(sdpa(qi, ks, vs, mask[None, None], impl=impl,
+                              scale=scale), "out_spec")
+        return _hint(sdpa(qi, k, v, None, impl=impl, scale=scale),
+                     "out_spec")
 
     # Remat each chunk: backward recomputes the chunk's scores instead of
     # saving fp32 softmax residuals stacked across all chunks (this is the
@@ -249,11 +261,12 @@ def attend(c: ModelConfig, q, k, v, *, causal: bool, impl: str,
     q_chunk = max(s // 8, Q_CHUNK) if unroll else 256
     if big and s % q_chunk == 0:
         return sdpa_chunked_q(q, k, v, causal=causal, window=c.attn_window,
-                              impl=impl, q_chunk=q_chunk, unroll=unroll)
+                              impl=impl, q_chunk=q_chunk, unroll=unroll,
+                              scale=score_scale(c))
     mask = None
     if causal:
         mask = make_causal_mask(s, t, c.attn_window)[None, None]
-    return sdpa(q, k, v, mask, impl=impl)
+    return sdpa(q, k, v, mask, impl=impl, scale=score_scale(c))
 
 
 # ---------------------------------------------------------------------------
@@ -268,7 +281,7 @@ def self_attention(c: ModelConfig, p: Params, x: jax.Array, *,
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    q, k, v = qkv_proj(c, p, x, positions if c.use_rope else None)
+    q, k, v = qkv_proj(c, p, x, positions if c.positions == "rope" else None)
     return out_proj(p, attend(c, q, k, v, causal=causal, impl=impl,
                               unroll=unroll))
 
@@ -281,7 +294,7 @@ def cross_attention(c: ModelConfig, p: Params, x: jax.Array,
     if "bq" in p:
         q = q + p["bq"]
     k, v = enc_kv
-    return out_proj(p, sdpa(q, k, v, None, impl=impl))
+    return out_proj(p, sdpa(q, k, v, None, impl=impl, scale=score_scale(c)))
 
 
 def encoder_kv(c: ModelConfig, p: Params, enc_out: jax.Array):
@@ -317,14 +330,15 @@ def prefill_attention(c: ModelConfig, p: Params, x: jax.Array, *,
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    q, k, v = qkv_proj(c, p, x, positions if c.use_rope else None)
+    q, k, v = qkv_proj(c, p, x, positions if c.positions == "rope" else None)
     assert prefix_kv is None or paged_prefix is None
     if paged_prefix is not None:
         from repro.kernels import ops as _kops
         k_pool, v_pool, k_scale, v_scale, tables, pimpl, pinterp = paged_prefix
         out = _kops.paged_prefill_attention(
             q, k, v, k_pool, v_pool, tables, window=c.attn_window,
-            impl=pimpl, interpret=pinterp, k_scale=k_scale, v_scale=v_scale)
+            impl=pimpl, interpret=pinterp, k_scale=k_scale, v_scale=v_scale,
+            scale=score_scale(c))
         return out_proj(p, out.astype(q.dtype)), (k, v)
     if prefix_kv is not None:
         pk, pv = prefix_kv
@@ -333,7 +347,8 @@ def prefill_attention(c: ModelConfig, p: Params, x: jax.Array, *,
         v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
         mask = make_causal_mask(s, t_pre + s, c.attn_window,
                                 q_offset=t_pre)[None, None]
-        out = out_proj(p, sdpa(q, k_full, v_full, mask, impl=impl))
+        out = out_proj(p, sdpa(q, k_full, v_full, mask, impl=impl,
+                               scale=score_scale(c)))
         return out, (k, v)
     out = out_proj(p, attend(c, q, k, v, causal=True, impl=impl,
                              unroll=unroll))
@@ -411,7 +426,7 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
     pos = jnp.asarray(pos, jnp.int32)
     per_slot = pos.ndim == 1
     positions = pos[:, None] if per_slot else jnp.full((b, 1), pos, jnp.int32)
-    q, k_new, v_new = qkv_proj(c, p, x, positions if c.use_rope else None)
+    q, k_new, v_new = qkv_proj(c, p, x, positions if c.positions == "rope" else None)
 
     if block_tables is not None:
         assert per_slot, "paged decode requires per-slot positions"
@@ -436,7 +451,7 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
             q[:, 0], cache_k, cache_v, block_tables[:, :nb], pos + 1,
             layer=layer, window=c.attn_window, impl=paged_impl,
             interpret=paged_interpret, k_scale=cache_k_scale,
-            v_scale=cache_v_scale)
+            v_scale=cache_v_scale, scale=score_scale(c))
         out = out_proj(p, out[:, None].astype(q.dtype))
         if quantized:
             return out, cache_k, cache_v, cache_k_scale, cache_v_scale
@@ -474,5 +489,6 @@ def decode_attention(c: ModelConfig, p: Params, x: jax.Array,
             mask = m[:, None, None, :]  # (B,1,1,T)
         else:
             mask = (kpos <= pos)[None, None, None, :]  # (1,1,1,T)
-    out = out_proj(p, sdpa(q, k_att, v_att, mask, impl=impl))
+    out = out_proj(p, sdpa(q, k_att, v_att, mask, impl=impl,
+                           scale=score_scale(c)))
     return out, cache_k, cache_v

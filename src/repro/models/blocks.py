@@ -52,6 +52,38 @@ def slot_kinds(c: ModelConfig) -> list[tuple[str, Optional[str]]]:
     return kinds
 
 
+def _res(c: ModelConfig, h: jax.Array) -> jax.Array:
+    """A residual branch's output times the configuration's multiplier."""
+    return h if c.resid_mult == 1.0 else h * jnp.asarray(c.resid_mult,
+                                                         h.dtype)
+
+
+def routes_share(c: ModelConfig) -> bool:
+    """Whether the stack's expert layers are the dropless held share
+    (``moe.moe_share_forward``), which counts the choices it routes."""
+    return c.moe_dispatch == "dropless" \
+        and any(ffn == "moe" for _m, ffn in slot_kinds(c))
+
+
+def _ffn(c: ModelConfig, sp: Params, ffn: Optional[str], x: jax.Array,
+         valid=None):
+    """The slot's FFN branch on ``x`` (already scaled): (new x, routed)."""
+    n = None
+    if ffn == "mlp":
+        x = x + _res(c, apply_mlp(c, sp["mlp"], apply_norm(c, sp["norm2"], x)))
+    elif ffn == "moe":
+        y, n, _ = moe_mod.moe_layer(c, sp["moe"],
+                                    apply_norm(c, sp["norm2"], x), valid)
+        x = x + _res(c, y)
+    return x, n
+
+
+def _total(ns: list):
+    """Sum of a period's routed counts (None where a slot routes none)."""
+    ns = [n for n in ns if n is not None]
+    return sum(ns[1:], ns[0]) if ns else None
+
+
 # ---------------------------------------------------------------------------
 # Init
 # ---------------------------------------------------------------------------
@@ -116,16 +148,18 @@ def _apply_slot(c: ModelConfig, sp: Params, x: jax.Array, *, mixer: str,
                                 unroll=unroll)
     else:
         h = ssm_mod.mamba_forward(c, sp["mamba"], h, unroll=unroll)
-    x = x + rh(h)
+    x = x + rh(_res(c, h))
     aux = jnp.zeros((), jnp.float32)
     if enc_kv is not None:
         h = apply_norm(c, sp["norm_x"], x)
         x = x + rh(attn.cross_attention(c, sp["cross"], h, enc_kv, impl=impl))
     if ffn == "mlp":
-        x = x + rh(apply_mlp(c, sp["mlp"], apply_norm(c, sp["norm2"], x)))
+        x = x + rh(_res(c, apply_mlp(c, sp["mlp"],
+                                     apply_norm(c, sp["norm2"], x))))
     elif ffn == "moe":
-        y, aux = moe_mod.moe_forward(c, sp["moe"], apply_norm(c, sp["norm2"], x))
-        x = x + rh(y)
+        y, _n, aux = moe_mod.moe_layer(c, sp["moe"],
+                                       apply_norm(c, sp["norm2"], x))
+        x = x + rh(_res(c, y))
     return x, aux
 
 
@@ -172,7 +206,7 @@ def stack_prefill(c: ModelConfig, layers: Params, x: jax.Array, *,
                   impl: str = "repeat", positions=None, enc_kv_stacked=None,
                   prefix_kv=None, paged_prefix=None, paged_tables=None,
                   paged_impl: str = "xla", paged_interpret: bool = False,
-                  unroll: bool = False):
+                  unroll: bool = False, valid_len=None, carry_slots=None):
     """Full-sequence causal pass that also emits per-layer caches.
 
     ``prefix_kv`` threads per-layer cached-prefix K/V (stacked like the
@@ -187,11 +221,23 @@ def stack_prefill(c: ModelConfig, layers: Params, x: jax.Array, *,
     k_scale/v_scale when int8) rides the scan as xs while the shared
     ``paged_tables`` (B, npre) addresses each row's prefix blocks —
     attention dispatches ``kernels.ops.paged_prefill_attention`` and the
-    dense prefix KV is never gathered out of the pool.
+    dense prefix KV is never gathered out of the pool. A stack with
+    mamba mixers resumes there too: ``carry_slots`` (B,) int32 names the
+    pool row whose conv and SSM state each row carries in (ids past the
+    pool start from zeros) — the chunk after a block-aligned prefix.
+
+    ``valid_len`` (B,) int32 counts each row's real tokens; the rest are
+    padding, which mamba mixers step over and expert layers route
+    nowhere. Returns (x, caches, routed): ``routed`` counts the real
+    tokens' choices that the dropless expert layers computed here (None
+    when the stack has none).
     """
     kinds = slot_kinds(c)
     assert enc_kv_stacked is None or prefix_kv is None
     assert prefix_kv is None or paged_prefix is None
+    valid = None
+    if valid_len is not None:
+        valid = jnp.arange(x.shape[1])[None, :] < valid_len[:, None]
 
     def body(carry, inp):
         x = carry
@@ -204,7 +250,7 @@ def stack_prefill(c: ModelConfig, layers: Params, x: jax.Array, *,
             period_params, ppx = inp
         else:
             period_params = inp
-        caches = {}
+        caches, routed = {}, []
         for i, (mixer, ffn) in enumerate(kinds):
             sp = period_params[f"slot{i}"]
             h = apply_norm(c, sp["norm1"], x)
@@ -222,24 +268,28 @@ def stack_prefill(c: ModelConfig, layers: Params, x: jax.Array, *,
                     paged_prefix=pp)
                 caches[f"slot{i}"] = {"k": k, "v": v}
             else:
-                assert pkv is None and ppx is None, (
-                    "prefix caching requires attention-only stacks")
+                assert pkv is None and (ppx is None or carry_slots is not None), (
+                    "a mamba mixer resumes only from its carried state")
+                h0 = conv0 = None
+                if ppx is not None:
+                    st = ppx[f"slot{i}"]
+                    h0, conv0 = (jnp.take(st[n], carry_slots, axis=0,
+                                          mode="fill", fill_value=0)
+                                 for n in ("ssm", "conv"))
                 h, (conv_tail, hstate) = ssm_mod.mamba_forward(
-                    c, sp["mamba"], h, return_state=True, unroll=unroll)
+                    c, sp["mamba"], h, h0=h0, conv0=conv0,
+                    lengths=valid_len, return_state=True, unroll=unroll)
                 caches[f"slot{i}"] = {"ssm": hstate, "conv": conv_tail}
-            x = x + h
+            x = x + _res(c, h)
             if ekv is not None:
                 hx = apply_norm(c, sp["norm_x"], x)
                 x = x + attn.cross_attention(
                     c, sp["cross"], hx,
                     (ekv[f"slot{i}"]["k"], ekv[f"slot{i}"]["v"]), impl=impl)
-            if ffn == "mlp":
-                x = x + apply_mlp(c, sp["mlp"], apply_norm(c, sp["norm2"], x))
-            elif ffn == "moe":
-                y, _ = moe_mod.moe_forward(c, sp["moe"],
-                                           apply_norm(c, sp["norm2"], x))
-                x = x + y
-        return x, caches
+            x, n = _ffn(c, sp, ffn, x, valid)
+            routed.append(n)
+        n = _total(routed)
+        return x, (caches if n is None else (caches, n))
 
     if enc_kv_stacked is not None:
         xs = (layers, enc_kv_stacked)
@@ -249,15 +299,19 @@ def stack_prefill(c: ModelConfig, layers: Params, x: jax.Array, *,
         xs = (layers, paged_prefix)
     else:
         xs = layers
-    x, caches = jax.lax.scan(body, x, xs, unroll=unroll)
-    return x, caches
+    x, ys = jax.lax.scan(body, x, xs, unroll=unroll)
+    if routes_share(c):
+        caches, n = ys
+        return x, caches, jnp.sum(n)
+    return x, ys, None
 
 
 def stack_decode(c: ModelConfig, layers: Params, x: jax.Array, caches: Params,
                  pos: jax.Array, *, impl: str = "grouped",
                  enc_kv_stacked=None, unroll: bool = False,
                  block_tables=None, n_kv_blocks: Optional[int] = None,
-                 paged_impl: str = "xla", paged_interpret: bool = False):
+                 paged_impl: str = "xla", paged_interpret: bool = False,
+                 live=None):
     """One-token decode through the stack, updating caches in place.
 
     ``block_tables`` selects the paged KV path: attention k/v cache
@@ -269,6 +323,11 @@ def stack_decode(c: ModelConfig, layers: Params, x: jax.Array, caches: Params,
     stacked back as ys, every pool would be copied whole each step.
     SSM/conv state leaves stay per-slot rows, scanned as xs/ys, in either
     layout.
+
+    ``live`` (B,) bool marks the rows that decode a real token: the other
+    rows keep their SSM/conv state (a slot between prefill chunks carries
+    it) and route nowhere. Returns (x, caches, routed) as
+    :func:`stack_prefill` does.
     """
     kinds = slot_kinds(c)
     pooled = {f"slot{i}" for i, (mixer, _) in enumerate(kinds)
@@ -280,10 +339,18 @@ def stack_decode(c: ModelConfig, layers: Params, x: jax.Array, caches: Params,
         layer_ids = jnp.arange(jax.tree.leaves(layers)[0].shape[0],
                                dtype=jnp.int32)
 
+    def keep(new, old):
+        if live is None:
+            return new
+        m = live.reshape((-1,) + (1,) * (new.ndim - 1))
+        return jnp.where(m, new, old.astype(new.dtype))
+
+    valid = None if live is None else live[:, None]
+
     def body(carry, inp):
         x, pools = carry
         period_params, cache, ekv, layer = inp
-        new_pools, new_rows = {}, {}
+        new_pools, new_rows, routed = {}, {}, []
         for i, (mixer, ffn) in enumerate(kinds):
             name = f"slot{i}"
             sp = period_params[name]
@@ -302,22 +369,24 @@ def stack_decode(c: ModelConfig, layers: Params, x: jax.Array, caches: Params,
                 sc = cache[name]
                 h, conv_s, ssm_s = ssm_mod.mamba_decode(c, sp["mamba"], h,
                                                         sc["conv"], sc["ssm"])
-                new_rows[name] = {"conv": conv_s, "ssm": ssm_s}
-            x = x + h
+                new_rows[name] = {"conv": keep(conv_s, sc["conv"]),
+                                  "ssm": keep(ssm_s, sc["ssm"])}
+            x = x + _res(c, h)
             if ekv is not None:
                 hx = apply_norm(c, sp["norm_x"], x)
                 x = x + attn.cross_attention(
                     c, sp["cross"], hx,
                     (ekv[name]["k"], ekv[name]["v"]), impl=impl)
-            if ffn == "mlp":
-                x = x + apply_mlp(c, sp["mlp"], apply_norm(c, sp["norm2"], x))
-            elif ffn == "moe":
-                y, _ = moe_mod.moe_forward(c, sp["moe"],
-                                           apply_norm(c, sp["norm2"], x))
-                x = x + y
-        return (x, new_pools), new_rows
+            x, n = _ffn(c, sp, ffn, x, valid)
+            routed.append(n)
+        n = _total(routed)
+        return (x, new_pools), (new_rows if n is None else (new_rows, n))
 
-    (x, pools), rows = jax.lax.scan(
+    (x, pools), ys = jax.lax.scan(
         body, (x, pools), (layers, rows, enc_kv_stacked, layer_ids),
         unroll=unroll)
-    return x, {**rows, **pools}
+    n = None
+    if routes_share(c):
+        ys, n = ys
+        n = jnp.sum(n)
+    return x, {**ys, **pools}, n

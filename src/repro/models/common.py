@@ -168,7 +168,7 @@ def embedding_init(key, c: ModelConfig) -> Params:
     p = {"tok": embed_init(k1, c.padded_vocab, c.d_model, pd)}
     if not c.tie_embeddings:
         p["head"] = embed_init(k2, c.padded_vocab, c.d_model, pd)
-    if not c.use_rope:
+    if c.positions == "learned":
         p["pos"] = embed_init(k3, c.max_position, c.d_model, pd)
     return p
 
@@ -176,7 +176,9 @@ def embedding_init(key, c: ModelConfig) -> Params:
 def embed_tokens(c: ModelConfig, p: Params, tokens: jax.Array,
                  positions: jax.Array | None = None) -> jax.Array:
     x = jnp.take(p["tok"], tokens, axis=0).astype(dtype_of(c))
-    if not c.use_rope and positions is not None:
+    if c.embed_mult != 1.0:
+        x = x * jnp.asarray(c.embed_mult, x.dtype)
+    if c.positions == "learned" and positions is not None:
         # gather keeps memory linear even for very long positions tables
         x = x + jnp.take(p["pos"], positions, axis=0).astype(dtype_of(c))
     return x
@@ -185,6 +187,8 @@ def embed_tokens(c: ModelConfig, p: Params, tokens: jax.Array,
 def unembed(c: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     table = p["tok"] if c.tie_embeddings else p["head"]
     logits = jnp.einsum("...d,vd->...v", x, table)
+    if c.logits_div != 1.0:
+        logits = logits / jnp.asarray(c.logits_div, logits.dtype)
     if c.logits_softcap:
         logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
     # mask vocab padding so it never receives probability mass

@@ -139,7 +139,10 @@ def prefill(c: ModelConfig, p: Params, tokens: jax.Array, *,
             prefix_kv: Params = None, pos_offset: int = 0,
             paged_prefix: Params = None,
             paged_tables: Optional[jax.Array] = None,
-            paged_impl: str = "xla", paged_interpret: bool = False):
+            paged_impl: str = "xla", paged_interpret: bool = False,
+            valid_len: Optional[jax.Array] = None,
+            carry_slots: Optional[jax.Array] = None,
+            return_routed: bool = False):
     """Process the prompt; return (last-position logits, caches, enc_kv).
 
     ``last_pos`` (B,) int32 overrides which position's logits are
@@ -158,7 +161,12 @@ def prefill(c: ModelConfig, p: Params, tokens: jax.Array, *,
     cache tree itself and the (B, npre) prefix block table — the prefix
     KV stays in the pool and attention walks the table via the paged
     prefill kernel (``paged_impl``/``paged_interpret`` select the
-    xla-ref vs Pallas vs interpret dispatch).
+    xla-ref vs Pallas vs interpret dispatch). ``carry_slots`` (B,) names
+    the pool rows whose recurrent state mamba mixers resume from.
+
+    ``valid_len`` (B,) int32 counts each row's real tokens (see
+    ``blocks.stack_prefill``). ``return_routed`` appends the int32 count
+    of real-token choices the dropless expert layers computed.
     """
     assert (prefix_kv is None) or (paged_prefix is None)
     has_prefix = (prefix_kv is not None) or (paged_prefix is not None)
@@ -170,14 +178,12 @@ def prefill(c: ModelConfig, p: Params, tokens: jax.Array, *,
     positions = None
     if has_prefix:
         positions = jnp.arange(tokens.shape[1])[None, :] + pos_offset
-    x, caches = blocks.stack_prefill(c, p["layers"], x, impl=impl,
-                                     enc_kv_stacked=enc_kv,
-                                     prefix_kv=prefix_kv,
-                                     paged_prefix=paged_prefix,
-                                     paged_tables=paged_tables,
-                                     paged_impl=paged_impl,
-                                     paged_interpret=paged_interpret,
-                                     positions=positions, unroll=unroll)
+    x, caches, routed = blocks.stack_prefill(
+        c, p["layers"], x, impl=impl, enc_kv_stacked=enc_kv,
+        prefix_kv=prefix_kv, paged_prefix=paged_prefix,
+        paged_tables=paged_tables, paged_impl=paged_impl,
+        paged_interpret=paged_interpret, positions=positions, unroll=unroll,
+        valid_len=valid_len, carry_slots=carry_slots)
     if last_pos is not None:
         x_last = jnp.take_along_axis(
             x, last_pos.astype(jnp.int32)[:, None, None], axis=1)
@@ -185,7 +191,13 @@ def prefill(c: ModelConfig, p: Params, tokens: jax.Array, *,
         x_last = x[:, -1:]
     x_last = apply_norm(c, p["final_norm"], x_last)
     logits = unembed(c, p["embed"], x_last)
+    if return_routed:
+        return logits, caches, enc_kv, _count(routed)
     return logits, caches, enc_kv
+
+
+def _count(routed) -> jax.Array:
+    return jnp.zeros((), jnp.int32) if routed is None else routed
 
 
 def decode_step(c: ModelConfig, p: Params, token: jax.Array, caches: Params,
@@ -193,20 +205,24 @@ def decode_step(c: ModelConfig, p: Params, token: jax.Array, caches: Params,
                 impl: str = "grouped", unroll: bool = False,
                 block_tables: Optional[jax.Array] = None,
                 n_kv_blocks: Optional[int] = None,
-                paged_impl: str = "xla", paged_interpret: bool = False):
+                paged_impl: str = "xla", paged_interpret: bool = False,
+                live: Optional[jax.Array] = None,
+                return_routed: bool = False):
     """token: (B, 1) int32; pos: scalar int32 OR per-row (B,) int32 (the
     continuous-batching engine decodes slots at independent positions).
     ``block_tables`` switches the attention layers onto the paged KV
-    pool (see ``blocks.stack_decode``). Returns (logits, caches)."""
+    pool (see ``blocks.stack_decode``); ``live`` (B,) bool marks the rows
+    that decode a real token. Returns (logits, caches), and the routed
+    count (see :func:`prefill`) with ``return_routed``."""
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None] if pos.ndim == 1 else jnp.full_like(token, pos)
     x = embed_tokens(c, p["embed"], token, positions)
-    x, caches = blocks.stack_decode(c, p["layers"], x, caches, pos,
-                                    impl=impl, enc_kv_stacked=enc_kv,
-                                    unroll=unroll, block_tables=block_tables,
-                                    n_kv_blocks=n_kv_blocks,
-                                    paged_impl=paged_impl,
-                                    paged_interpret=paged_interpret)
+    x, caches, routed = blocks.stack_decode(
+        c, p["layers"], x, caches, pos, impl=impl, enc_kv_stacked=enc_kv,
+        unroll=unroll, block_tables=block_tables, n_kv_blocks=n_kv_blocks,
+        paged_impl=paged_impl, paged_interpret=paged_interpret, live=live)
     x = apply_norm(c, p["final_norm"], x)
     logits = unembed(c, p["embed"], x)
+    if return_routed:
+        return logits, caches, _count(routed)
     return logits, caches

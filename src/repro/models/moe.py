@@ -6,6 +6,13 @@ O(T^2) FLOPs per group and would dominate the roofline. With the expert dim
 sharded over the mesh ``model`` axis, GSPMD lowers the scatter/gather pair
 to all-to-all collectives (expert parallelism).
 
+``moe_share_forward`` is the dropless layer (``moe_dispatch ==
+"dropless"``): it holds experts ``[0, experts_held)`` of the ``n_experts``
+the router scores (one chip's share of an expert-parallel deployment), and
+computes only their part of the routed result, by a grouped matmul over
+the held experts, dropping no token. ``moe_layer`` dispatches between the
+two.
+
 ``moe_forward_dense`` is the pure/naive oracle used by tests.
 """
 from __future__ import annotations
@@ -57,17 +64,18 @@ def _buf_hint(x):
 
 
 def moe_init(key, c: ModelConfig) -> Params:
+    """Router over all ``n_experts``; weights of the held experts only."""
     pd = param_dtype_of(c)
     eff = c.expert_d_ff or c.d_ff
     ks = jax.random.split(key, c.n_experts + 2)
-    experts = [mlp_init(ks[i], c, eff) for i in range(c.n_experts)]
+    experts = [mlp_init(ks[i], c, eff) for i in range(c.experts_held)]
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *experts)
     p = {
         "router": dense_init(ks[-1], c.d_model, c.n_experts, jnp.float32),
         "experts": stacked,
     }
-    if c.moe_shared:
-        p["shared"] = mlp_init(ks[-2], c, eff)
+    if c.shared_d_ff:
+        p["shared"] = mlp_init(ks[-2], c, c.shared_d_ff)
     return p
 
 
@@ -140,9 +148,74 @@ def moe_forward(c: ModelConfig, p: Params, x: jax.Array):
     wk = (weights.reshape(-1, 1) * keep[:, None]).astype(x.dtype)
     y = (gathered * wk).reshape(T, c.top_k, d).sum(axis=1)
 
-    if c.moe_shared:
+    if c.shared_d_ff:
         y = y + apply_mlp(c, p["shared"], x2d)
     return y.reshape(b, s, d), aux
+
+
+def _grouped_mlp(c: ModelConfig, experts: Params, xs: jax.Array,
+                 sizes: jax.Array) -> jax.Array:
+    """Per-expert MLP of the rows ``xs`` (R, D), sorted by expert with
+    ``sizes[e]`` rows of expert ``e``; rows past ``sizes.sum()`` give 0."""
+    def mm(a, w):
+        return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes)
+    if c.act == "swiglu":
+        h = jax.nn.silu(mm(xs, experts["wi_gate"])) * mm(xs, experts["wi_up"])
+    else:
+        h = jax.nn.gelu(mm(xs, experts["wi"]))
+    return mm(h, experts["wo"])
+
+
+def route_rows(c: ModelConfig) -> int:
+    """Rows of the grouped-matmul buffer per token: a token's ``top_k``
+    experts are distinct, so at most ``min(top_k, experts_held)`` of its
+    choices land here."""
+    return min(c.top_k, c.experts_held)
+
+
+def moe_share_forward(c: ModelConfig, p: Params, x: jax.Array,
+                      valid: jax.Array | None = None):
+    """The held experts' share of the layer, dropping no token.
+
+    x: (B, S, D); ``valid`` (B, S) bool marks real tokens (the rest are
+    routed nowhere). The router scores all ``n_experts`` and keeps its
+    ``top_k``, softmaxed over their logits; the choices that land on a
+    held expert (ids below ``experts_held``) are sorted by expert into a
+    static buffer of ``B*S*route_rows`` rows and go through one grouped
+    matmul a weight. The shared expert is added in full. Returns
+    (y (B, S, D), routed (int32: real-token choices on held experts),
+    aux_loss).
+    """
+    b, s, d = x.shape
+    t = b * s
+    x2d = x.reshape(t, d)
+    weights, idx, aux = router_topk(c, p, x2d)
+    k, held = c.top_k, c.experts_held
+    mine = idx < held
+    if valid is not None:
+        mine = mine & valid.reshape(t, 1)
+    key = jnp.where(mine, idx, held).reshape(-1)              # (T*k,)
+    order = jnp.argsort(key, stable=True)[:t * route_rows(c)]
+    sizes = jnp.zeros((held,), jnp.int32).at[key].add(1, mode="drop")
+    rows = order // k
+    out = _grouped_mlp(c, p["experts"], x2d[rows], sizes)
+    w = weights.reshape(-1)[order][:, None]
+    out = jnp.where((key[order] < held)[:, None],
+                    out.astype(jnp.float32) * w, 0.0)
+    y = jnp.zeros((t, d), jnp.float32).at[rows].add(out).astype(x.dtype)
+    if c.shared_d_ff:
+        y = y + apply_mlp(c, p["shared"], x2d)
+    return y.reshape(b, s, d), jnp.sum(mine, dtype=jnp.int32), aux
+
+
+def moe_layer(c: ModelConfig, p: Params, x: jax.Array,
+              valid: jax.Array | None = None):
+    """The expert layer as ``c.moe_dispatch`` says: (y, routed, aux_loss),
+    ``routed`` None on the capacity path, which counts nothing."""
+    if c.moe_dispatch == "dropless":
+        return moe_share_forward(c, p, x, valid)
+    y, aux = moe_forward(c, p, x)
+    return y, None, aux
 
 
 def moe_forward_einsum(c: ModelConfig, p: Params, x: jax.Array):
@@ -172,7 +245,7 @@ def moe_forward_einsum(c: ModelConfig, p: Params, x: jax.Array):
     y = jnp.einsum("tef,te,efd->td", h, wfull.astype(h.dtype), ex["wo"])
     if "bo" in ex:
         y = y + jnp.einsum("te,ed->td", wfull.astype(h.dtype), ex["bo"])
-    if c.moe_shared:
+    if c.shared_d_ff:
         y = y + apply_mlp(c, p["shared"], x2d)
     return y.reshape(b, s, d), aux
 
@@ -183,11 +256,11 @@ def moe_forward_dense(c: ModelConfig, p: Params, x: jax.Array):
     x2d = x.reshape(b * s, d)
     weights, experts_idx, aux = router_topk(c, p, x2d)
     y = jnp.zeros_like(x2d)
-    for e in range(c.n_experts):
+    for e in range(c.experts_held):
         pe = jax.tree.map(lambda w: w[e], p["experts"])
         ye = apply_mlp(c, pe, x2d)
         w_e = jnp.where(experts_idx == e, weights, 0.0).sum(-1)  # (T,)
         y = y + ye * w_e[:, None].astype(x.dtype)
-    if c.moe_shared:
+    if c.shared_d_ff:
         y = y + apply_mlp(c, p["shared"], x2d)
     return y.reshape(b, s, d), aux

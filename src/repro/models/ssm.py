@@ -36,10 +36,15 @@ def mamba_init(key, c: ModelConfig) -> Params:
     }
 
 
-def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise causal conv. xbc: (B, S, C); w: (K, C)."""
+def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array,
+                 state: jax.Array | None = None) -> jax.Array:
+    """Depthwise causal conv. xbc: (B, S, C); w: (K, C); ``state``
+    (B, K-1, C): the inputs before the first position (zeros if None)."""
     k = w.shape[0]
-    pad = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+    if state is None:
+        pad = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+    else:
+        pad = jnp.concatenate([state.astype(xbc.dtype), xbc], axis=1)
     # unrolled taps: K is tiny (4); avoids conv layout headaches under SPMD
     out = sum(pad[:, i:i + xbc.shape[1], :] * w[i] for i in range(k))
     return jax.nn.silu((out + b).astype(jnp.float32)).astype(xbc.dtype)
@@ -164,15 +169,37 @@ def ssd_decode_step(h: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
 
 def mamba_forward(c: ModelConfig, p: Params, x: jax.Array,
                   h0: jax.Array | None = None, return_state: bool = False,
-                  unroll: bool = False):
-    """x: (B, S, D) -> (B, S, D). Chunked SSD over the sequence."""
+                  unroll: bool = False, conv0: jax.Array | None = None,
+                  lengths: jax.Array | None = None):
+    """x: (B, S, D) -> (B, S, D). Chunked SSD over the sequence.
+
+    ``h0`` (B, nh, hp, n) and ``conv0`` (B, K-1, C) are the SSM and conv
+    state carried in from the positions before this call (zeros if None),
+    so a prompt can be fed in pieces. ``lengths`` (B,) int32 marks each
+    row's valid positions ``[0, length)``: later positions get ``dt = 0``
+    (the SSD state passes through them unchanged) and the returned conv
+    tail is the last K-1 valid inputs of [carried state ++ this call].
+    """
     b, s, _ = x.shape
     di, ns, nh, hp = c.d_inner, c.ssm_state, c.ssm_nheads, c.ssm_headdim
+    km1 = c.ssm_conv - 1
     z, xbc_raw, dt_raw = _split_proj(c, x @ p["in_proj"])
-    conv_tail = xbc_raw[:, -(c.ssm_conv - 1):, :]  # for decode continuity
-    xbc = _causal_conv(xbc_raw, p["conv_w"], p["conv_b"])
+    if conv0 is None and lengths is None:
+        conv_tail = xbc_raw[:, -km1:, :]  # for decode continuity
+    else:
+        hist = jnp.concatenate(
+            [jnp.zeros((b, km1, xbc_raw.shape[-1]), xbc_raw.dtype)
+             if conv0 is None else conv0.astype(xbc_raw.dtype), xbc_raw],
+            axis=1)                                    # (b, K-1+s, C)
+        end = jnp.full((b,), s, jnp.int32) if lengths is None else lengths
+        at = end[:, None] + jnp.arange(km1)[None, :]   # last K-1 valid
+        conv_tail = jnp.take_along_axis(hist, at[..., None], axis=1)
+    xbc = _causal_conv(xbc_raw, p["conv_w"], p["conv_b"], conv0)
     xin, B, C = xbc[..., :di], xbc[..., di:di + ns], xbc[..., di + ns:]
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (b,s,nh)
+    if lengths is not None:
+        dt = jnp.where((jnp.arange(s)[None, :] < lengths[:, None])[..., None],
+                       dt, 0.0)
     A = -jnp.exp(p["A_log"])  # (nh,)
     xh = xin.reshape(b, s, nh, hp)
     xdt = xh * dt[..., None].astype(xh.dtype)

@@ -55,10 +55,14 @@ Two scheduler modes share the loop (``sched=``):
     (``Request.n_replay`` / ``Slot.replay``) — decode built that KV the
     first time, and prefill's attention numerics are not bit-equal to
     decode's, so replay is what keeps a resumed stream bit-identical.
-    Requires the paged cache and an attention-only family. Greedy
-    argmax streams are bit-identical to phased: chunked prefill
-    computes the same causal attention in block-aligned slices, and
-    every KV row is built by the same program phased used for it.
+    Requires the paged cache. Greedy argmax streams are bit-identical
+    to phased: chunked prefill computes the same causal attention in
+    block-aligned slices, and every KV row is built by the same program
+    phased used for it. Stacks with mamba mixers (ssm/hybrid) carry
+    their conv and SSM state from chunk to chunk: a chunk after the
+    first gathers its slot's state rows into the program, and the
+    decode step leaves the rows of slots that are not decoding as they
+    are.
 
 Energy: the engine reads its ``PowerMethod`` list synchronously at every
 step-window boundary, so each prefill/decode window is bracketed by
@@ -78,7 +82,15 @@ until the next arrival); the first call of each program key runs inside
 ``serve.first_call``, so a compile inside a traced window shows by
 name. A prefill or decode span's ``step`` kwarg is the index of its
 ``StepRecord``, whose counters (``iter``, ``rows``, ``width``,
-``used``) the span carries too. Only ints go in kwargs, and no span adds
+``used``) the span carries too. On stacks with mamba mixers a prefill
+span carries ``carried`` (rows that resume a recurrent state); on
+stacks with dropless expert layers prefill and decode spans carry
+``moe_rows`` (grouped-matmul buffer rows the call's programs hold,
+summed over expert layers), and the ``serve.emit`` span after a fetch
+carries ``moe_local``: the real-token routed choices that landed on
+held experts, counted on the device by every program since the last
+fetch and fetched with it (``moe_local_prefill``: the prefill
+programs' part). Only ints go in kwargs, and no span adds
 a device sync: with the profiler off a span costs a context entry.
 
 ``serve_step`` (single-token decode against a full KV cache) is what the
@@ -101,7 +113,8 @@ from repro.core.metrics import (
     ServeSummary, attribute_energy, serve_summary,
 )
 from repro.core.runner import StragglerWatchdog
-from repro.models import lm
+from repro.models import blocks, lm
+from repro.models.moe import route_rows
 from repro.serve.cache import (
     CacheOOM, PagedKVCache, copy_blocks, grow_caches,
     insert_paged_prefill, insert_rows, slotted_cache,
@@ -251,15 +264,24 @@ class ServeEngine:
             self._decode = jax.jit(decode,
                                    donate_argnums=(2,) if donate else ())
             self._grow = jax.jit(grow_caches, static_argnums=(1,))
+            #: mamba mixers carry recurrent state between prefill chunks
+            self._carries = c.family in ("ssm", "hybrid")
+            #: dropless expert layers count the choices they route
+            self._counts = blocks.routes_share(c)
+            #: the programs need each row's real length (padding steps
+            #: no recurrence and routes to no expert)
+            self._rowwise = self._carries or self._counts
+            self._moe_layers = sum(map(c.is_moe_layer, range(c.n_layers)))
+            #: device counts not yet fetched: (prefill?, count)
+            self._routed: list = []
             # serve programs: batched prefill returning per-row argmax
             # first tokens; single-step decode returning next tokens so
             # fused runs chain the token stream on device
             def serve_prefill(params, tokens, last_pos):
-                logits, caches, _ = lm.prefill(c, params, tokens,
-                                               impl=impl_prefill,
-                                               last_pos=last_pos)
-                first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-                return first, caches
+                out = lm.prefill(c, params, tokens, impl=impl_prefill,
+                                 last_pos=last_pos, **self._row_kw(last_pos))
+                first = jnp.argmax(out[0][:, -1], -1).astype(jnp.int32)
+                return (first, out[1]) + tuple(out[3:])
             self._serve_prefill = jax.jit(serve_prefill)
 
             def serve_step(params, tok, caches, pos):
@@ -286,6 +308,51 @@ class ServeEngine:
     # ------------------------------------------------------------------
     # Model-backed cache + program construction
     # ------------------------------------------------------------------
+
+    def _row_kw(self, last) -> dict:
+        """What a prefill program passes ``lm.prefill`` beside its
+        tokens: each row's real length (``last + 1``; pad rows have
+        ``last == -1``) and the routed count, where the stack needs
+        them."""
+        kw: dict = {}
+        if self._rowwise:
+            kw["valid_len"] = last + 1
+        if self._counts:
+            kw["return_routed"] = True
+        return kw
+
+    def _keep_routed(self, fn: Callable, prefill: bool) -> Callable:
+        """``fn`` whose program returns a routed count last: the count
+        stays on the device, kept until the next fetch, and the other
+        outputs are returned."""
+        if not self._counts:
+            return fn
+
+        def call(*args):
+            *out, n = fn(*args)
+            self._routed.append((prefill, n))
+            return tuple(out)
+        return call
+
+    def _fetch_routed(self) -> dict:
+        """The counts kept since the last fetch, summed, as the
+        ``serve.emit`` kwargs; called right after a fetch the engine
+        makes anyway, when the programs that counted have run."""
+        if not self._counts:
+            return {}
+        got = jax.device_get([n for _p, n in self._routed])
+        kw = {"moe_local": int(sum(got)),
+              "moe_local_prefill": int(sum(
+                  g for (p, _n), g in zip(self._routed, got) if p))}
+        self._routed = []
+        return kw
+
+    def _moe_rows(self, tokens: int) -> dict:
+        """``moe_rows`` span kwarg: grouped-matmul buffer rows for
+        ``tokens`` token rows, over every expert layer."""
+        if not self._counts:
+            return {}
+        return {"moe_rows": tokens * route_rows(self.c) * self._moe_layers}
 
     def _ensure_cache(self):
         if self.caches is not None:
@@ -318,23 +385,37 @@ class ServeEngine:
 
     def _paged_step_fn(self, nb: int):
         """Decode program gathering ``nb`` block-table columns (static —
-        one compiled program per bucket, reused across steps)."""
+        one compiled program per bucket, reused across steps). Stacks with
+        per-row state also take ``live`` (n_slots,) bool, the rows that
+        decode a real token (the scheduler's active mask); a caller that
+        gives none (a warm-up) runs the same program with every row
+        parked."""
         fn = self._paged_steps.get(nb)
         if fn is None:
             c = self.c
 
-            def step(params, tok, caches, pos, tables):
-                logits, caches = lm.decode_step(
+            def step(params, tok, caches, pos, tables, live=None):
+                kw: dict = {}
+                if live is not None:
+                    kw["live"] = live
+                if self._counts:
+                    kw["return_routed"] = True
+                out = lm.decode_step(
                     c, params, tok[:, None], caches, pos,
                     impl=self.impl_decode, block_tables=tables,
                     n_kv_blocks=nb, paged_impl=self.paged_impl,
-                    paged_interpret=self.paged_interpret)
-                return (jnp.argmax(logits[:, -1], -1).astype(jnp.int32),
-                        caches)
+                    paged_interpret=self.paged_interpret, **kw)
+                return (jnp.argmax(out[0][:, -1], -1).astype(jnp.int32),
+                        ) + tuple(out[1:])
 
-            fn = self._first_call(
+            fn = self._keep_routed(self._first_call(
                 jax.jit(step, donate_argnums=(2,) if self.donate else ()),
-                nb=nb)
+                nb=nb), prefill=False)
+            if self._rowwise:
+                program, parked = fn, jnp.zeros((self.n_slots,), bool)
+
+                def fn(params, tok, caches, pos, tables, live=parked):
+                    return program(params, tok, caches, pos, tables, live)
             self._paged_steps[nb] = fn
         return fn
 
@@ -357,7 +438,8 @@ class ServeEngine:
         """The batched prefill program at one prompt bucket (no prefix)."""
         fn = self._cold_prefills.get(bucket)
         if fn is None:
-            fn = self._first_call(self._serve_prefill, bucket=bucket, npre=0)
+            fn = self._keep_routed(self._first_call(
+                self._serve_prefill, bucket=bucket, npre=0), prefill=True)
             self._cold_prefills[bucket] = fn
         return fn
 
@@ -371,24 +453,27 @@ class ServeEngine:
         One compiled program per (suffix bucket, prefix depth) pair. The
         pool is read, never donated — the suffix rows scatter in via
         ``insert_paged_prefill`` afterwards, exactly like a cold
-        prefill."""
+        prefill. Stacks with mamba mixers also take ``slot_ids``, the
+        pool rows whose conv and SSM state each row resumes from."""
         key = (bucket, npre)
         fn = self._prefix_prefills.get(key)
         if fn is None:
             c, bs = self.c, self.block_size
             impl = self.impl_prefill
 
-            def prefill_hit(params, caches, tokens, last, pre_blocks):
-                logits, rows, _ = lm.prefill(
+            def prefill_hit(params, caches, tokens, last, pre_blocks,
+                            slot_ids=None):
+                out = lm.prefill(
                     c, params, tokens, impl=impl, last_pos=last,
                     paged_prefix=caches, paged_tables=pre_blocks,
                     pos_offset=npre * bs, paged_impl=self.paged_impl,
-                    paged_interpret=self.paged_interpret)
-                first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-                return first, rows
+                    paged_interpret=self.paged_interpret,
+                    carry_slots=slot_ids, **self._row_kw(last))
+                first = jnp.argmax(out[0][:, -1], -1).astype(jnp.int32)
+                return (first, out[1]) + tuple(out[3:])
 
-            fn = self._first_call(jax.jit(prefill_hit), bucket=bucket,
-                                  npre=npre)
+            fn = self._keep_routed(self._first_call(
+                jax.jit(prefill_hit), bucket=bucket, npre=npre), prefill=True)
             self._prefix_prefills[key] = fn
         return fn
 
@@ -408,21 +493,13 @@ class ServeEngine:
         return min(b, cap)
 
     def _prompt_bucket(self, n: int) -> int:
-        """Prompt-length bucket for batched prefill.
-
-        Attention-only stacks round up to the next ``block_size``
-        multiple — causal masking hides the pad tokens' KV until decode
-        overwrites it, so coarse buckets are free and cut trace count.
-        Stacks with mamba layers (ssm/hybrid) must prefill at the EXACT
-        prompt length: the SSD recurrence and conv tail run *through*
-        trailing pad tokens and would carry corrupted state into decode
-        (masking protects attention KV only), so each distinct length
-        is its own group (the pre-batching behaviour, still batched
-        across same-length requests). Partial paged blocks are
-        zero-padded by ``insert_paged_rows``.
+        """Prompt-length bucket for batched prefill: the next
+        ``block_size`` multiple — causal masking hides the pad tokens'
+        KV until decode overwrites it, and mamba mixers step over the
+        padding (``valid_len``), so coarse buckets are free and cut trace
+        count. Partial paged blocks are zero-padded by
+        ``insert_paged_rows``.
         """
-        if self.c.family in ("ssm", "hybrid"):
-            return n
         b = -(-n // self.block_size) * self.block_size
         return min(max(b, self.block_size), self.max_len)
 
@@ -615,11 +692,12 @@ class ServeEngine:
                 - pre_len * len(entries)
             with TraceAnnotation("serve.prefill", step=len(steps),
                                  iter=self._iter, rows=kp, bucket=bucket,
-                                 npre=npre, used=used):
+                                 npre=npre, used=used,
+                                 **self._moe_rows(kp * bucket)):
                 t0 = self.clock()
                 self._sample_power(ts, ws)   # bracket the prefill window
                 tokens = np.zeros((kp, bucket), np.int32)
-                last = np.zeros((kp,), np.int32)
+                last = np.full((kp,), -1, np.int32)   # pad rows: no tokens
                 # pad rows: dropped
                 slot_ids = np.full((kp,), self.n_slots, np.int32)
                 # pad rows gather the trash block — harmless, never read back
@@ -668,6 +746,7 @@ class ServeEngine:
                                               jnp.asarray(slot_ids))
             with TraceAnnotation("serve.prefill.fetch"):
                 first_np = np.asarray(first)  # single batched device fetch
+                routed = self._fetch_routed()
             t1 = self.clock()
             self._sample_power(ts, ws)
             rids = tuple(s.request.rid for s, _pre in entries)
@@ -675,7 +754,7 @@ class ServeEngine:
             steps.append(StepRecord("prefill", t0, t1, rids, len(rids),
                                     iter=self._iter, rows=kp, width=bucket,
                                     used=used))
-            with TraceAnnotation("serve.emit"):
+            with TraceAnnotation("serve.emit", **routed):
                 for i, slot in enumerate(slots):
                     res = results[slot.request.rid]
                     res.slot = slot.index
@@ -763,13 +842,16 @@ class ServeEngine:
             kp = self.n_slots
             pre_len = npre * self.block_size
             used = sum(end - start for _slot, start, end in entries)
+            kw = self._moe_rows(kp * bucket)
+            if self._carries:
+                kw["carried"] = len(entries) if npre else 0
             with TraceAnnotation("serve.prefill", step=len(steps),
                                  iter=self._iter, rows=kp, bucket=bucket,
-                                 npre=npre, used=used):
+                                 npre=npre, used=used, **kw):
                 t0 = self.clock()
                 self._sample_power(ts, ws)   # bracket the chunk window
                 tokens = np.zeros((kp, bucket), np.int32)
-                last = np.zeros((kp,), np.int32)
+                last = np.full((kp,), -1, np.int32)   # pad rows: no tokens
                 slot_ids = np.full((kp,), self.n_slots, np.int32)
                 pre_blocks = np.zeros((kp, npre), np.int32)
                 for i, (slot, start, end) in enumerate(entries):
@@ -781,9 +863,11 @@ class ServeEngine:
                         pre_blocks[i] = self._paged.block_ids(slot.index,
                                                               pre_len)
                 if npre:
+                    # a resumed recurrence gathers its slot's state rows
+                    carry = (jnp.asarray(slot_ids),) if self._carries else ()
                     first, rows = self._prefix_prefill_fn(bucket, npre)(
                         self.params, self.caches, jnp.asarray(tokens),
-                        jnp.asarray(last), jnp.asarray(pre_blocks))
+                        jnp.asarray(last), jnp.asarray(pre_blocks), *carry)
                 else:
                     first, rows = self._cold_prefill_fn(bucket)(
                         self.params, jnp.asarray(tokens), jnp.asarray(last))
@@ -801,9 +885,11 @@ class ServeEngine:
             emitting = [(i, s) for i, s in finals
                         if not s.request.n_replay]
             first_np = None
+            routed: dict = {}
             if emitting:
                 with TraceAnnotation("serve.prefill.fetch"):
                     first_np = np.asarray(first)
+                    routed = self._fetch_routed()
             t1 = self.clock()
             self._sample_power(ts, ws)
             rids = tuple(s.request.rid for s, _s, _e in entries)
@@ -814,7 +900,7 @@ class ServeEngine:
                                     len(emitting), fetched=bool(emitting),
                                     iter=self._iter, rows=kp, width=bucket,
                                     used=used))
-            with TraceAnnotation("serve.emit"):
+            with TraceAnnotation("serve.emit", **routed):
                 for slot, _start, end in entries:
                     slot.prefill_pos = end
                     slot.pos = end
@@ -940,6 +1026,8 @@ class ServeEngine:
                 nb = self._nb_bucket(self._paged.max_owned())
                 step = self._paged_step_fn(nb)
                 extra = (tables,)
+                if self._rowwise:
+                    extra += (jnp.asarray(sched.active_mask()),)
                 # micro-step i attends the blocks of pos + i + 1 tokens
                 bs = self.block_size
                 used = sum(min((s.pos + i) // bs + 1, nb)
@@ -947,7 +1035,8 @@ class ServeEngine:
             else:
                 step = self._serve_step
                 extra = ()
-            span.set_metadata(k=k, nb=nb, rows=self.n_slots, used=used)
+            span.set_metadata(k=k, nb=nb, rows=self.n_slots, used=used,
+                              **self._moe_rows(self.n_slots * k))
             tok = jnp.asarray(sched.input_tokens())
             pos0 = sched.positions()
             adv = sched.active_mask().astype(np.int32)  # idle rows stay parked
@@ -967,13 +1056,14 @@ class ServeEngine:
             self.caches = caches
         with TraceAnnotation("serve.decode.fetch"):
             outs_np = [np.asarray(o) for o in outs]  # pipeline drain: 1 sync
+            routed = self._fetch_routed()
         t1 = self.clock()
         self._sample_power(ts, ws)
         if self.watchdog is not None:
             self.watchdog.observe(self._decode_idx, (t1 - t0) / k)
         self._decode_idx += 1
         emitted = 0
-        with TraceAnnotation("serve.emit"):
+        with TraceAnnotation("serve.emit", **routed):
             for out in outs_np:
                 for s in active:
                     if s.request is None:  # finished at an earlier micro-step
@@ -1082,8 +1172,7 @@ class ServeEngine:
         ``sched``/``chunk_tokens`` override the engine defaults for
         this run: ``"chunked"`` interleaves block-aligned prefill
         slices with decode steps and backs decode growth with
-        preemption (see module docstring) — paged cache, model mode,
-        attention-only families.
+        preemption (see module docstring) — paged cache, model mode.
 
         Degradation: requests carrying a ``deadline_s`` are SHED (zero
         tokens, reason "shed") if still queued past their admission
@@ -1121,11 +1210,7 @@ class ServeEngine:
                 "cannot rebuild an emitted tail bit-identically")
         if not self._scripted:
             self._ensure_cache()
-            if chunked:
-                assert self.c.family not in ("ssm", "hybrid"), (
-                    "chunked prefill re-enters the prompt mid-sequence "
-                    "via prefix_kv — attention-only families (a mamba "
-                    "recurrence cannot restart at a block boundary)")
+            self._routed = []
             self.preemptions = 0
         self.shed = 0
         self.injected_faults = 0

@@ -1,5 +1,7 @@
 """Per-arch smoke tests (reduced configs): forward + one train step on CPU,
 output shapes + no NaNs; prefill==forward; decode continuity."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,8 +88,27 @@ def test_prefill_matches_forward(arch):
 @pytest.mark.parametrize("arch", ["llama3.2-3b", "mamba2-1.3b",
                                   "jamba-v0.1-52b"])
 def test_decode_continuity(arch):
-    """decode_step at position s must match teacher-forced forward."""
+    """decode_step at position s must match teacher-forced forward: within
+    bf16 tolerance, with the same argmax, at the config's dtype, and to
+    float32 rounding, with the same argmax, when the model runs in
+    float32. Jamba has no position encoding, and its reduced model's top
+    two logits nearly tie: in bf16 its argmax has to agree only where
+    their margin exceeds the tolerance."""
     c = get_config(arch).reduced()
+    got, want = _continuity(c)
+    np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
+    agree = np.argmax(got, -1) == np.argmax(want, -1)
+    if arch == "jamba-v0.1-52b":
+        top2 = np.sort(want, -1)[:, -2:]
+        agree |= top2[:, 1] - top2[:, 0] <= 5e-2
+    assert agree.mean() > 0.9
+    c32 = dataclasses.replace(c, dtype="float32", param_dtype="float32")
+    got, want = _continuity(c32)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    assert (np.argmax(got, -1) == np.argmax(want, -1)).all()
+
+
+def _continuity(c):
     b, s = 2, 64
     key = jax.random.key(1)
     full = jax.random.randint(key, (b, s + 1), 0, c.vocab, jnp.int32)
@@ -104,7 +125,4 @@ def test_decode_continuity(arch):
         caches)
     logits_d, _ = lm.decode_step(c, params, full[:, s:s + 1], caches,
                                  jnp.int32(s), enc_kv=enc_kv)
-    got = np.asarray(logits_d[:, -1], np.float32)
-    np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
-    # and the argmax agrees (bf16 tolerance)
-    assert (np.argmax(got, -1) == np.argmax(want, -1)).mean() > 0.9
+    return np.asarray(logits_d[:, -1], np.float32), want

@@ -38,7 +38,7 @@ def test_capacity_drops_bounded():
     y, _ = moe.moe_forward(c, p, x)
     assert bool(jnp.all(jnp.isfinite(y)))
     # dropped-token rows are exactly zero (before shared expert)
-    if not c.moe_shared:
+    if not c.shared_d_ff:
         norms = jnp.linalg.norm(y.reshape(-1, c.d_model), axis=-1)
         assert float((norms == 0).mean()) > 0  # something dropped
 
@@ -55,12 +55,12 @@ def test_router_topk_normalized():
 
 
 def test_shared_expert_added():
-    c = _cfg(moe_shared=True, capacity_factor=8.0)
+    c = _cfg(shared_d_ff=64, capacity_factor=8.0)
     p = moe.moe_init(jax.random.key(0), c)
     x = jax.random.normal(jax.random.key(1), (1, 16, c.d_model), jnp.float32)
     y_with, _ = moe.moe_forward(c, p, x)
     p_no = dict(p)
-    c_no = dataclasses.replace(c, moe_shared=False)
+    c_no = dataclasses.replace(c, shared_d_ff=0)
     y_without, _ = moe.moe_forward(c_no, p_no, x)
     assert float(jnp.abs(y_with - y_without).max()) > 1e-6
 
