@@ -42,7 +42,7 @@ Two scheduler modes share the loop (``sched=``):
     prefill (the TTFT/TPOT cliff the chunked mode removes);
   * ``chunked`` — iteration-level scheduling proper: each loop
     iteration runs at most one ``chunk_tokens`` prefill slice per
-    mid-prefill slot (batched across slots), then one decode step for
+    mid-prefill slot (one program call each), then one decode step for
     every fully-prefilled slot. Chunk boundaries are block-aligned, so
     every chunk after the first reuses the suffix-prefill program
     (``prefix_kv`` gathered from the slot's own blocks). Admission
@@ -805,8 +805,15 @@ class ServeEngine:
     def _model_prefill_chunks(self, sched: Scheduler, results, steps,
                               ts, ws, chunk_tokens: int) -> None:
         """Run ONE ``chunk_tokens`` prefill slice for every mid-prefill
-        slot, batched per (suffix-bucket, prefix-depth) group — the
-        chunked scheduler's per-iteration prefill quantum.
+        slot — the chunked scheduler's per-iteration prefill quantum —
+        as one program call per slot, in (suffix-bucket, prefix-depth)
+        order.
+
+        Each call carries its slot's row alone: a batch padded to the
+        slot count would run the whole model over rows that hold no
+        token, and a (bucket, depth) group seldom holds more than one
+        slot. The programs stay keyed by (bucket, depth), so the set a
+        warm-up reaches is the set a run calls.
 
         Chunk ``j`` is just a suffix prefill against the slot's own
         first ``prefill_pos / block_size`` blocks, so it reuses the
@@ -816,7 +823,7 @@ class ServeEngine:
         emits the first token exactly like phased prefill and registers
         the full prompt with the prefix index. Pool growth for a chunk
         may preempt a younger slot — possibly one in this very wave,
-        which then drops out before grouping."""
+        which then drops out before its call."""
         slots = [s for s in sched.slots if s.prefilling]
         # grow pools oldest-first so preemption flows old -> young
         for slot in sorted(slots,
@@ -828,111 +835,104 @@ class ServeEngine:
             end = min(slot.prefill_pos + chunk_tokens,
                       slot.prefill_target)
             self._ensure_with_preempt(sched, slot, end, results)
-        groups: dict[tuple, list] = {}
+        chunks = []
         for slot in slots:
             if not slot.prefilling:
                 continue
             start = slot.prefill_pos
             end = min(start + chunk_tokens, slot.prefill_target)
-            npre = start // self.block_size
-            bucket = self._prompt_bucket(end - start)
-            groups.setdefault((bucket, npre), []).append(
-                (slot, start, end))
-        for (bucket, npre), entries in sorted(groups.items()):
-            kp = self.n_slots
-            pre_len = npre * self.block_size
-            used = sum(end - start for _slot, start, end in entries)
-            kw = self._moe_rows(kp * bucket)
-            if self._carries:
-                kw["carried"] = len(entries) if npre else 0
-            with TraceAnnotation("serve.prefill", step=len(steps),
-                                 iter=self._iter, rows=kp, bucket=bucket,
-                                 npre=npre, used=used, **kw):
-                t0 = self.clock()
-                self._sample_power(ts, ws)   # bracket the chunk window
-                tokens = np.zeros((kp, bucket), np.int32)
-                last = np.full((kp,), -1, np.int32)   # pad rows: no tokens
-                slot_ids = np.full((kp,), self.n_slots, np.int32)
-                pre_blocks = np.zeros((kp, npre), np.int32)
-                for i, (slot, start, end) in enumerate(entries):
-                    prompt = np.asarray(slot.request.prompt, np.int32)
-                    tokens[i, :end - start] = prompt[start:end]
-                    last[i] = end - start - 1
-                    slot_ids[i] = slot.index
-                    if npre:
-                        pre_blocks[i] = self._paged.block_ids(slot.index,
-                                                              pre_len)
-                if npre:
-                    # a resumed recurrence gathers its slot's state rows
-                    carry = (jnp.asarray(slot_ids),) if self._carries else ()
-                    first, rows = self._prefix_prefill_fn(bucket, npre)(
-                        self.params, self.caches, jnp.asarray(tokens),
-                        jnp.asarray(last), jnp.asarray(pre_blocks), *carry)
-                else:
-                    first, rows = self._cold_prefill_fn(bucket)(
-                        self.params, jnp.asarray(tokens), jnp.asarray(last))
-                nbk = -(-bucket // self.block_size)
-                blocks = np.full((kp, nbk), self._paged.n_blocks, np.int32)
-                for i, (slot, start, end) in enumerate(entries):
-                    own = self._paged.block_ids(slot.index, end)[npre:]
-                    blocks[i, :len(own)] = own
-                self.caches = insert_paged_prefill(
-                    self.caches, rows, jnp.asarray(blocks),
-                    jnp.asarray(slot_ids), block_size=self.block_size)
-            finals = [(i, slot)
-                      for i, (slot, _s, end) in enumerate(entries)
-                      if end == slot.prefill_target]
-            emitting = [(i, s) for i, s in finals
-                        if not s.request.n_replay]
-            first_np = None
-            routed: dict = {}
-            if emitting:
-                with TraceAnnotation("serve.prefill.fetch"):
-                    first_np = np.asarray(first)
-                    routed = self._fetch_routed()
-            t1 = self.clock()
-            self._sample_power(ts, ws)
-            rids = tuple(s.request.rid for s, _s, _e in entries)
-            # window energy splits across every chunking request;
-            # n_tokens counts only the first tokens actually emitted,
-            # keeping the credited-token accounting exact
-            steps.append(StepRecord("prefill", t0, t1, rids,
-                                    len(emitting), fetched=bool(emitting),
-                                    iter=self._iter, rows=kp, width=bucket,
-                                    used=used))
-            with TraceAnnotation("serve.emit", **routed):
-                for slot, _start, end in entries:
-                    slot.prefill_pos = end
-                    slot.pos = end
-                for i, slot in finals:
-                    req = slot.request
-                    if req.n_replay:
-                        # resume: the original prompt is back in cache; the
-                        # emitted tail now replays through the decode program
-                        # as forced inputs (this chunk's argmax is a prefill
-                        # recompute of an already-emitted token — discard it,
-                        # decode's version is the stream's ground truth). No
-                        # prefix registration either: the tail blocks would
-                        # hold decode-built KV, and an adopter's phased twin
-                        # would prefill them — bit-divergence by adoption.
-                        slot.replay = req.n_replay
-                        slot.last_token = int(req.prompt[slot.prefill_pos])
-                        continue
-                    if self.prefix_cache:
-                        self.prefix_stats["registered_blocks"] += \
-                            self._paged.prefix_register(
-                                slot.index, [int(t) for t in req.prompt])
-                    res = results[req.rid]
-                    # a resume that had already emitted keeps its stamp
-                    if res.first_token_s == 0.0:
-                        res.first_token_s = t1
-                    tok = int(first_np[i])
-                    res.tokens.append(tok)
-                    slot_index = slot.index
-                    reason = sched.record_token(slot, tok)
-                    if reason is not None:
-                        res.finish_s, res.finish_reason = t1, reason
-                        self._free_paged_slot(slot_index)
+            chunks.append((self._prompt_bucket(end - start),
+                           start // self.block_size, slot, start, end))
+        chunks.sort(key=lambda e: e[:2])   # stable: slot order kept
+        for bucket, npre, slot, start, end in chunks:
+            self._prefill_chunk(sched, results, steps, ts, ws,
+                                slot, start, end, bucket, npre)
+
+    def _prefill_chunk(self, sched: Scheduler, results, steps, ts, ws,
+                       slot: Slot, start: int, end: int, bucket: int,
+                       npre: int) -> None:
+        """One slot's prompt slice ``[start, end)`` as one batch-1 call of
+        the (``bucket``, ``npre``) program, its rows scattered into the
+        slot's blocks; the final slice emits the first token."""
+        used = end - start
+        kw = self._moe_rows(bucket)
+        if self._carries:
+            kw["carried"] = int(npre > 0)
+        with TraceAnnotation("serve.prefill", step=len(steps),
+                             iter=self._iter, rows=1, bucket=bucket,
+                             npre=npre, used=used, **kw):
+            t0 = self.clock()
+            self._sample_power(ts, ws)   # bracket the chunk window
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :used] = np.asarray(slot.request.prompt,
+                                          np.int32)[start:end]
+            last = jnp.asarray([used - 1], jnp.int32)
+            slot_ids = jnp.asarray([slot.index], jnp.int32)
+            if npre:
+                pre_blocks = self._paged.block_ids(
+                    slot.index, npre * self.block_size)
+                # a resumed recurrence gathers its slot's state rows
+                carry = (slot_ids,) if self._carries else ()
+                first, rows = self._prefix_prefill_fn(bucket, npre)(
+                    self.params, self.caches, jnp.asarray(tokens), last,
+                    jnp.asarray(pre_blocks[None]), *carry)
+            else:
+                first, rows = self._cold_prefill_fn(bucket)(
+                    self.params, jnp.asarray(tokens), last)
+            nbk = -(-bucket // self.block_size)
+            blocks = np.full((1, nbk), self._paged.n_blocks, np.int32)
+            own = self._paged.block_ids(slot.index, end)[npre:]
+            blocks[0, :len(own)] = own
+            self.caches = insert_paged_prefill(
+                self.caches, rows, jnp.asarray(blocks), slot_ids,
+                block_size=self.block_size)
+        req = slot.request
+        final = end == slot.prefill_target
+        emitting = final and not req.n_replay
+        routed: dict = {}
+        if emitting:
+            with TraceAnnotation("serve.prefill.fetch"):
+                tok = int(np.asarray(first)[0])
+                routed = self._fetch_routed()
+        t1 = self.clock()
+        self._sample_power(ts, ws)
+        # n_tokens counts only a first token actually emitted, keeping
+        # the credited-token accounting exact
+        steps.append(StepRecord("prefill", t0, t1, (req.rid,),
+                                int(emitting), fetched=emitting,
+                                iter=self._iter, rows=1, width=bucket,
+                                used=used))
+        with TraceAnnotation("serve.emit", **routed):
+            slot.prefill_pos = end
+            slot.pos = end
+            if not final:
+                return
+            if req.n_replay:
+                # resume: the original prompt is back in cache; the
+                # emitted tail now replays through the decode program
+                # as forced inputs (this chunk's argmax is a prefill
+                # recompute of an already-emitted token — discard it,
+                # decode's version is the stream's ground truth). No
+                # prefix registration either: the tail blocks would
+                # hold decode-built KV, and an adopter's phased twin
+                # would prefill them — bit-divergence by adoption.
+                slot.replay = req.n_replay
+                slot.last_token = int(req.prompt[slot.prefill_pos])
+                return
+            if self.prefix_cache:
+                self.prefix_stats["registered_blocks"] += \
+                    self._paged.prefix_register(
+                        slot.index, [int(t) for t in req.prompt])
+            res = results[req.rid]
+            # a resume that had already emitted keeps its stamp
+            if res.first_token_s == 0.0:
+                res.first_token_s = t1
+            res.tokens.append(tok)
+            slot_index = slot.index
+            reason = sched.record_token(slot, tok)
+            if reason is not None:
+                res.finish_s, res.finish_reason = t1, reason
+                self._free_paged_slot(slot_index)
 
     def _decode_plan(self, sched: Scheduler, active,
                      admission_blocked: bool = False,

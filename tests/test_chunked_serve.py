@@ -92,6 +92,57 @@ def test_chunked_streams_match_phased_with_prefix_cache(setup):
         assert pref.prefix_stats["hit_requests"] == 1, mode
 
 
+class _Spans:
+    """Stands in for ``TraceAnnotation``: records each span's kwargs."""
+
+    seen: list = []
+
+    def __init__(self, name, **kw):
+        _Spans.seen.append((name, kw))
+
+    def set_metadata(self, **kw):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *a):
+        return False
+
+
+def test_slots_at_one_bucket_and_depth_prefill_in_calls_of_their_own(
+        setup, monkeypatch):
+    """Four prompts of 66-80 tokens chunk in step: every iteration finds
+    all four at one (bucket, depth), and each slot's slice runs in a
+    batch-1 call of its own — the programs still keyed by (bucket,
+    depth) alone — with streams bit-identical to phased."""
+    import repro.serve.engine as E
+    c, _ = setup
+    rng = np.random.default_rng(29)
+    reqs = [Request(rid=i, prompt=rng.integers(0, c.vocab, p, np.int32),
+                    max_new_tokens=8, arrival_s=0.0)
+            for i, p in enumerate([66, 70, 75, 80])]
+    want = _streams(_engine(setup, n_slots=4).serve(
+        list(reqs), policy="continuous", sched="phased"))
+    _Spans.seen = []
+    monkeypatch.setattr(E, "TraceAnnotation", _Spans)
+    out = _engine(setup, n_slots=4).serve(list(reqs), policy="continuous",
+                                          sched="chunked")
+    assert _streams(out) == want
+    pre = [s for s in out.steps if s.kind == "prefill"]
+    # three 32-token-bucketed slices each: (32, 0), (32, 2), (16, 4)
+    assert [(s.iter, s.rids) for s in pre] == [
+        (it, (rid,)) for it in range(3) for rid in range(4)]
+    assert all(s.rows == 1 and s.used <= s.width for s in pre)
+    assert [s.used for s in pre] == [32] * 8 + [2, 6, 11, 16]
+    spans = [kw for n, kw in _Spans.seen if n == "serve.prefill"]
+    reached = {(kw["bucket"], kw["npre"]) for kw in spans}
+    assert reached == {(32, 0), (32, 2), (16, 4)}
+    first = {(kw["bucket"], kw["npre"]) for n, kw in _Spans.seen
+             if n == "serve.first_call" and "bucket" in kw}
+    assert first == reached
+
+
 # ---------------------------------------------------------------------------
 # Preemption: oversubscribed pool completes, streams stay bit-identical
 # ---------------------------------------------------------------------------

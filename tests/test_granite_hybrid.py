@@ -218,19 +218,24 @@ def _widest_gap(params, res, prompts):
     return worst
 
 
-@pytest.mark.parametrize("case", ["mixed", "preempted"])
+@pytest.mark.parametrize("case", ["mixed", "preempted", "in_step"])
 def test_chunked_engine_matches_the_reference(params, monkeypatch, case):
     """Prompts longer than a chunk resume their Mamba state chunk by
-    chunk; rows of different lengths share a call (a second chunk of 8
-    and one of 13 tokens); an oversubscribed pool preempts the younger
-    request, which prefills again and replays its tail."""
+    chunk, each slot's slice in a call of its own: second chunks of 8
+    and 13 tokens at one (bucket, depth); an oversubscribed pool
+    preempts the younger request, which prefills again and replays its
+    tail; four prompts chunk in step, so each iteration's four calls
+    share one program and each gathers its own slot's state."""
     rng = np.random.default_rng(7)
     if case == "mixed":
         shapes, kw = [(40, 6), (45, 5), (100, 4), (9, 7)], {
             "n_slots": 4, "max_len": 128}
-    else:
+    elif case == "preempted":
         shapes, kw = [(40, 50), (36, 45)], {
             "n_slots": 2, "max_len": 96, "n_blocks": 9}
+    else:
+        shapes, kw = [(66, 5), (70, 6), (75, 4), (80, 7)], {
+            "n_slots": 4, "max_len": 96}
     prompts = {i: rng.integers(0, TINY.vocab, p, np.int32)
                for i, (p, _n) in enumerate(shapes)}
     reqs = [Request(rid=i, prompt=prompts[i], max_new_tokens=n,
@@ -242,12 +247,19 @@ def test_chunked_engine_matches_the_reference(params, monkeypatch, case):
     assert _widest_gap(params, out.results, prompts) < GAP_TOL
     pre = [k for n, k in _Spans.seen if n == "serve.prefill"]
     assert any(k["carried"] for k in pre)
+    assert all(k["rows"] == 1 and k["carried"] == int(k["npre"] > 0)
+               for k in pre)
+    calls = [(k["iter"], k["bucket"], k["npre"], k["used"]) for k in pre]
     if case == "mixed":
         assert eng.preemptions == 0
-        assert (16, 2, 21) in {(k["bucket"], k["npre"], k["used"])
-                               for k in pre}
-    else:
+        assert {(16, 2, 8), (16, 2, 13)} <= {c[1:] for c in calls}
+    elif case == "preempted":
         assert eng.preemptions >= 1
+    else:
+        assert eng.preemptions == 0
+        assert calls == [(it, b, d, u) for it, (b, d, us) in enumerate(
+            [(32, 0, [32] * 4), (32, 2, [32] * 4), (16, 4, [2, 6, 11, 16])])
+            for u in us]
 
 
 def test_routed_counts_reach_the_emit_spans(params, monkeypatch):

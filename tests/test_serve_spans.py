@@ -54,16 +54,19 @@ def test_chunked_counters_match_hand_counts(setup):
     eng = _engine(setup, "chunked")
     out = eng.serve(_requests(setup[0]))
     assert _counts(out) == [
-        # iter 0: the first 32-token chunk of each prompt, one (32, 0)
-        # group: 20 + 32 + 32 real tokens in 3 x 32; rid 0 is prefilled
-        ("prefill", 0, (0, 1, 2), 3, 32, 84, 1),
+        # iter 0: the first 32-token chunk of each prompt, each in a
+        # call of its own at (32, 0): 20, 32 and 32 real tokens in 1 x
+        # 32; rid 0 is prefilled
+        ("prefill", 0, (0,), 1, 32, 20, 1),
+        ("prefill", 0, (1,), 1, 32, 32, 1),
+        ("prefill", 0, (2,), 1, 32, 32, 1),
         # rid 0 decodes alone (a chunk is pending: k = 1) at pos 20:
         # 2 live blocks, every slot owns 2, nb = 2
         ("decode", 0, (0,), 3, 2, 2, 1),
         # iter 1: rid 1's last 8 tokens and rid 2's last 32 after the
-        # 2 cached blocks: two (bucket, depth) groups, two calls
-        ("prefill", 1, (1,), 3, 16, 8, 1),
-        ("prefill", 1, (2,), 3, 32, 32, 1),
+        # 2 cached blocks, at (16, 2) and (32, 2)
+        ("prefill", 1, (1,), 1, 16, 8, 1),
+        ("prefill", 1, (2,), 1, 32, 32, 1),
         # all three decode, k = 2 (rid 2's budget); rid 2 grows to 5
         # blocks, nb = 6 (capped at max_len / 16); live blocks per
         # step: rid 0 at pos 21, 22: 2 + 2; rid 1 at 40, 41: 3 + 3;
@@ -73,10 +76,13 @@ def test_chunked_counters_match_hand_counts(setup):
         ("decode", 2, (0, 1), 3, 4, 5, 1),
         ("decode", 3, (0,), 3, 2, 2, 1),
         # iters 4-23 wait out the arrival at 1.0 s; rid 3 at iter 24
-        ("prefill", 24, (3,), 3, 32, 24, 1),
+        ("prefill", 24, (3,), 1, 32, 24, 1),
         ("decode", 24, (3,), 3, 2, 2, 1),
     ]
-    assert all(s.fetched for s in out.steps)
+    # rid 1's and rid 2's first chunks emit nothing, so their windows
+    # end without a fetch; every other window fetches
+    assert [s.fetched for s in out.steps] == [
+        True, False, False, True, True, True, True, True, True, True, True]
 
 
 def test_phased_counters_match_hand_counts(setup):
